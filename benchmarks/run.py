@@ -33,32 +33,8 @@ from repro.core import (accelerator, dse, energymodel, hetero, partition,
                         rs_mapping, topology)
 from repro.core import autoshard
 from repro.core.tpu_costmodel import ShardingPolicy, step_time
+from repro.launch.compile_cache import enable_compile_cache
 
-
-def _enable_persistent_cache() -> dict:
-    """Opt-in JAX persistent compilation cache (REPRO_JAX_CACHE_DIR).
-
-    Cuts the 7–14.5 s per-level cold compiles on repeat runs/CI by
-    serving XLA executables from disk.  NOTE this does NOT make
-    ``jit_cold_cache_hit`` true — that field reports the in-process
-    TRACE cache (a fresh process always retraces); the persistent cache
-    only shortens the compile underneath, visible as a lower
-    ``jit_cold_s``.  The payload records it separately so cold numbers
-    are never misread (see docs/bench_schema.md)."""
-    cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR")
-    info = dict(enabled=False, dir=cache_dir or None)
-    if not cache_dir:
-        return info
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache everything — the engine's kernels are many small programs
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        info["enabled"] = True
-    except Exception as exc:               # pragma: no cover - version skew
-        info["error"] = f"{type(exc).__name__}: {exc}"
-    return info
 
 OUT = Path("experiments/tables")
 BENCH_DSE_JSON = Path("BENCH_dse.json")
@@ -1238,12 +1214,10 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
     nets = QUICK_NETS if args.quick else PAPER_NETS
-    cache_info = _enable_persistent_cache()
+    cache_info = dict(enabled=True, dir=enable_compile_cache())
 
     print("name,us_per_call,derived")
-    if cache_info.get("dir"):
-        _emit("persistent_cache", 0.0,
-              f"enabled={cache_info['enabled']} dir={cache_info['dir']}")
+    _emit("persistent_cache", 0.0, f"dir={cache_info['dir']}")
     sweeps, us = _timed(lambda: _sweeps(nets))
     _emit("dse_sweep_all", us, f"{len(nets)} networks x 150 configs")
     levels = bench_dse_scale(quick=args.quick)

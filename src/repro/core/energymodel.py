@@ -56,7 +56,9 @@ count-terms kernel (:mod:`repro.kernels.count_terms`), and the numpy
 reference.  An unavailable choice degrades silently at the result level
 but emits ONE :class:`RuntimeWarning` per process per degradation edge
 (see :func:`resolve_backend`); :func:`last_backend` always reports what
-actually executed.
+actually executed.  On a TPU the device path is ``"jax"``: the Pallas
+kernel does not lower there, and asking for it raises
+:class:`BackendUnavailable` (see :func:`platform`).
 """
 
 from __future__ import annotations
@@ -347,9 +349,8 @@ def simulate_network(cfg: AcceleratorConfig, layers: Sequence[Layer],
 # across sweeps: jax.jit keys on input shapes, and the layer axis is padded
 # to multiples of _LAYER_BUCKET, so every network (all 18 paper benchmarks
 # are ≤ 251 layers) shares one trace per grid size.  The kernel needs 64-bit
-# floats (access counts exceed float32's exact-integer range); jax ≥ 0.4
-# removed ``jax.enable_x64`` so the x64 scope comes from
-# ``jax.experimental.enable_x64`` and wraps both trace and execution.
+# floats (access counts exceed float32's exact-integer range), so every
+# trace and dispatch of it runs inside :func:`x64`.
 # ---------------------------------------------------------------------------
 
 _LAYER_BUCKET = 256
@@ -362,6 +363,14 @@ _JIT_STATS = {"traces": 0, "calls": 0}
 
 def jit_cache_stats() -> Dict[str, int]:
     return dict(_JIT_STATS)
+
+
+def x64():
+    """The one 64-bit scope of every jitted engine and solver program:
+    a context manager that enables float64/int64 for the traces and
+    dispatches inside it and restores the process setting on exit."""
+    import jax
+    return jax.enable_x64(True)
 
 
 def _cfg_struct_from_grid(xp, grid) -> Dict[str, Any]:
@@ -747,10 +756,10 @@ def _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
     In per-layer mode the all-gathered partials are [n_u, L] instead of
     [n_u, n_net] — heavier across the mesh, but the split along the
     unique-config axis (and the replicated combine) is identical."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = _cfg_mesh()
     row2, row1, rep = P("cfg", None), P("cfg"), P()
@@ -778,12 +787,12 @@ def _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
             else lax.all_gather(s, "cfg", axis=0, tiled=True)
             for i, s in enumerate(S))
 
-    S = shard_map(
+    S = jax.shard_map(
         local, mesh=mesh,
         in_specs=({k: rep for k in cfg_m}, {k: row2 for k in cfg_u},
                   {k: rep for k in lay}, row1),
         out_specs=tuple(rep for _ in range(14)),
-        check_rep=False)(cfg_m, cfg_u, lay, inv_m)
+        check_vma=False)(cfg_m, cfg_u, lay, inv_m)
     if per_layer:
         e, t = _gather_combine_body(jnp, S, inv, coefs)
         return (_split_layers(jnp, e, segments),
@@ -799,17 +808,41 @@ def jax_available() -> bool:
         return False
 
 
+def platform() -> str:
+    """The platform the device path runs on (``jax.default_backend()``:
+    ``"cpu"``, ``"tpu"``, ...); ``"cpu"`` without jax.  Every platform
+    decision of the engine — which backend is the device path, whether
+    Pallas kernels run interpreted — reads this one function."""
+    if not jax_available():
+        return "cpu"                                   # pragma: no cover
+    import jax
+    return jax.default_backend()
+
+
+#: Why the fused count-terms Pallas kernel cannot run on a TPU.
+_PALLAS_TPU_REFUSAL = (
+    "Mosaic refuses the count-terms tile program: it is float64 ('64-bit "
+    "types are not supported'), and in float32 it fails to legalize; it "
+    "needs an exact non-f64 count format first (ROADMAP Queue 1 item 2)")
+
+
+class BackendUnavailable(RuntimeError):
+    """An explicitly requested backend cannot run on this platform, and
+    degrading to another would hide the device (see
+    :func:`resolve_backend`)."""
+
+
 def pallas_available() -> bool:
-    """Whether the fused count-terms Pallas kernel can run (interpret
-    mode, which works on any jax backend — a native TPU/GPU lowering is
-    opt-in, see ``repro.kernels.count_terms.count_term_sums``)."""
+    """Whether the fused count-terms Pallas kernel can run here: on the
+    CPU it runs interpreted; on a TPU the tile program does not lower
+    (``_PALLAS_TPU_REFUSAL``), so the device path there is ``"jax"``."""
     if not jax_available():
         return False                                   # pragma: no cover
     try:
         from jax.experimental import pallas            # noqa: F401
-        return True
     except Exception:                                  # pragma: no cover
         return False
+    return platform() != "tpu"
 
 
 #: Selectable heavy-stage backends, in auto-fallback order.
@@ -852,7 +885,9 @@ def resolve_backend(backend: str | None = None,
     raising, so ``backend="pallas"`` is safe on hosts without Pallas.
     Each degradation edge emits one ``RuntimeWarning`` per process (not
     per call); the silent paths are only the auto-selections where
-    nothing was requested."""
+    nothing was requested.  On a platform where the Pallas kernel cannot
+    lower (a TPU) an explicit ``"pallas"`` raises
+    :class:`BackendUnavailable` instead of degrading."""
     if backend is None:
         if use_jax is None:
             backend = "jax" if jax_available() else "numpy"
@@ -864,6 +899,10 @@ def resolve_backend(backend: str | None = None,
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, "
                          f"got {backend!r}")
+    if backend == "pallas" and platform() == "tpu":
+        raise BackendUnavailable(
+            f"backend 'pallas' cannot run on a TPU: {_PALLAS_TPU_REFUSAL}; "
+            "use backend='jax'")
     if backend == "pallas" and not pallas_available():
         backend = "jax"
     if backend == "jax" and not jax_available():
@@ -1003,8 +1042,7 @@ def _eval_fields(fields, lay, segments, backend: str, shard: bool,
         e, t = _np_grid_kernel(segments, cfg_m, cfg_u, lay, inv_m, inv,
                                coefs, per_layer=per_layer)
         return np.asarray(e), np.asarray(t)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with x64():
         args = (cfg_m, cfg_u, lay, inv_m, inv, coefs)
         if n_dev > 1:
             args = _device_put_sharded(*args)
@@ -1064,7 +1102,6 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
         return e, t
 
     import jax
-    from jax.experimental import enable_x64
     devs = jax.devices()
     n_dev = len(devs) if shard else 1
     pending: list = []
@@ -1074,7 +1111,7 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
         e[start:stop] = np.asarray(ec)[:stop - start]
         t[start:stop] = np.asarray(tc)[:stop - start]
 
-    with enable_x64():
+    with x64():
         for ci, start, stop, fc in chunks():
             dev = devs[ci % n_dev] if n_dev > 1 else None
             ec, tc = _dispatch_chunk(fc, lay, segments, dev, backend,
@@ -1578,11 +1615,10 @@ def stream_networks(grid: ConfigGrid,
         # Round-robin the chunk kernels across devices (async dispatch);
         # the cheap stateful reduction runs in chunk order on device 0.
         import jax
-        from jax.experimental import enable_x64
         devs = jax.devices()
         pending: list = []
 
-        with enable_x64():
+        with x64():
             def reduce_one(item):
                 nonlocal state
                 ci, start, stop, e_d, t_d, fc = item
@@ -1927,11 +1963,10 @@ def stream_layer_topk(grid: ConfigGrid,
             emit(ci)
     else:
         import jax
-        from jax.experimental import enable_x64
         devs = jax.devices()
         n_dev = host_device_count() if shard else 1
         pending: list = []
-        with enable_x64():
+        with x64():
             def reduce_one(item):
                 nonlocal state
                 ci, start, stop, e_d, t_d, fc = item

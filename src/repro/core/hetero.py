@@ -104,6 +104,18 @@ def _greedy_cover(cand: np.ndarray, rel: np.ndarray, max_cores: int):
     return cols, assign, uncovered
 
 
+def _boundary_rel(stream, name: str) -> np.ndarray:
+    """A streamed boundary set's metric over its own minimum, both in host
+    float64.  The set holds the network's argmin, so the argmin and every
+    row tied with it get exactly 1.0 on every backend.  Dividing by the
+    fold's ``min_metric`` instead would mix arithmetics: on a TPU the fold
+    computes ``e * t`` in emulated float64, a rounding step away from the
+    host's product, and the ties among the networks' minima that order the
+    pool top-up would break by rounding noise."""
+    m = stream.boundary_metric(name)
+    return m / m.min() if m.size else m
+
+
 def _row_key(grid: ConfigGrid, i: int) -> Tuple[float, ...]:
     """Hashable config-row key of one grid point: two grid points with
     identical columns are the SAME core type, whatever their flat index."""
@@ -222,7 +234,7 @@ def design_chip_streaming(stream: "energymodel.StreamResult",
     for i, nm in enumerate(names):
         pos = np.searchsorted(union, stream.boundary_idx[nm])
         cand[i, pos] = True
-        rel[i, pos] = stream.boundary_metric(nm) / stream.min_metric[i]
+        rel[i, pos] = _boundary_rel(stream, nm)
 
     cols, assign, uncovered = _greedy_cover(cand, rel, max_cores)
     core_flat = [int(union[c]) for c in cols]
@@ -521,7 +533,7 @@ def codesign_problems_streaming(grid: ConfigGrid,
     for j, nm in enumerate(names):
         pos = np.searchsorted(pts, stream.boundary_idx[nm])
         cand[j, pos] = True
-        rel[j, pos] = stream.boundary_metric(nm) / stream.min_metric[j]
+        rel[j, pos] = _boundary_rel(stream, nm)
         tkj = stream.topk_idx[:, j]
         valid = tkj >= 0
         pos = np.searchsorted(pts, tkj[valid])

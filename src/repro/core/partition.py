@@ -59,7 +59,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from .energymodel import _bucketed, jax_available
+from .energymodel import _bucketed, jax_available, x64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -398,8 +398,7 @@ def batch_partition(latencies: Sequence[Sequence[float]],
         kkp = np.concatenate([kk, np.ones(pad, np.int64)])
         lop = np.concatenate([lo, np.full(pad, lo[0] if n_rows else 0.0)])
         hip = np.concatenate([hi, np.full(pad, hi[0] if n_rows else 1.0)])
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with x64():
             bs_steps = int(np.ceil(np.log2(n_pad + 1))) + 1
             starts = np.asarray(_jax_solver()(
                 P, netp, n_ap, kkp, lop, hip, _K_MAX, bs_steps))[:n_rows]
@@ -447,13 +446,16 @@ def batch_partition(latencies: Sequence[Sequence[float]],
 # ---------------------------------------------------------------------------
 
 
-def _pareto_body(xp, value, latency, norm_latency, deadlines):
+def _pareto_body(xp, value, latency, norm_latency, deadlines, n_net):
     """Traced body shared by the numpy and jitted paths.
 
     ``value``/``latency``/``norm_latency``: [C, N] per-(chip, network)
     score (normalised energy by convention), raw pipeline bottleneck, and
     normalised bottleneck; ``deadlines``: [N, D] absolute per-network
-    latency bounds.  Returns
+    latency bounds; ``n_net``: N as a float64 operand.  Means over the
+    network axis divide by it rather than by a trace-time constant, which
+    XLA:CPU turns into a multiply by the rounded reciprocal, one ulp off
+    numpy's division.  Returns
 
     * ``masked``  [C, N, D] — ``value`` where the schedule meets the
       deadline, +inf where it misses,
@@ -468,7 +470,7 @@ def _pareto_body(xp, value, latency, norm_latency, deadlines):
       (value, norm_latency) plane."""
     feas = latency[:, :, None] <= deadlines[None, :, :]
     masked = xp.where(feas, value[:, :, None], np.inf)
-    scores = masked.mean(axis=1)                              # [C, D]
+    scores = masked.sum(axis=1) / n_net                       # [C, D]
     best = xp.where(xp.isfinite(scores).any(axis=0),
                     xp.argmin(scores, axis=0), -1)
     best_net = xp.where(xp.isfinite(masked).any(axis=0),
@@ -479,7 +481,8 @@ def _pareto_body(xp, value, latency, norm_latency, deadlines):
     dom = (e2 <= e1) & (l2 <= l1) & ((e2 < e1) | (l2 < l1))
     net_front = ~dom.any(axis=1)                              # [C, N]
 
-    mv, ml = value.mean(axis=1), norm_latency.mean(axis=1)
+    mv = value.sum(axis=1) / n_net
+    ml = norm_latency.sum(axis=1) / n_net
     domc = ((mv[None, :] <= mv[:, None]) & (ml[None, :] <= ml[:, None])
             & ((mv[None, :] < mv[:, None]) | (ml[None, :] < ml[:, None])))
     chip_front = ~domc.any(axis=1)                            # [C]
@@ -495,9 +498,9 @@ def _jax_pareto():
         import jax
         import jax.numpy as jnp
 
-        def kernel(value, latency, norm_latency, deadlines):
+        def kernel(value, latency, norm_latency, deadlines, n_net):
             return _pareto_body(jnp, value, latency, norm_latency,
-                                deadlines)
+                                deadlines, n_net)
 
         _jitted_pareto = jax.jit(kernel)
     return _jitted_pareto
@@ -524,13 +527,14 @@ def batch_pareto_scores(value, latency, deadlines,
                                     (value.shape[1], deadlines.shape[0]))
     norm_latency = (latency if norm_latency is None
                     else np.asarray(norm_latency, dtype=np.float64))
+    n_net = np.float64(value.shape[1])
     use_jax = jax_available() if use_jax is None else use_jax
     if use_jax:
-        from jax.experimental import enable_x64
-        with enable_x64():
-            out = _jax_pareto()(value, latency, norm_latency, deadlines)
+        with x64():
+            out = _jax_pareto()(value, latency, norm_latency, deadlines,
+                                n_net)
         return tuple(np.asarray(o) for o in out)
-    return _pareto_body(np, value, latency, norm_latency, deadlines)
+    return _pareto_body(np, value, latency, norm_latency, deadlines, n_net)
 
 
 def partition_network(report, n_cores: int, method: str = "bb") -> Partition:
@@ -847,8 +851,7 @@ def batch_schedule_hetero(latencies, counts,
     l_idx = np.arange(n_pad)
     valid_l = l_idx[None, :] < n_lens_p[:, None]              # [B, L]
     if use_jax:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with x64():
             masked, tt, n_t, mx = (
                 np.asarray(o) for o in _jax_hetero_stage1()(
                     lat, avail, n_lens_p))
@@ -950,8 +953,7 @@ def batch_schedule_hetero(latencies, counts,
             kkp = np.concatenate([kk_n, np.ones(pad, np.int64)])
             lop = np.concatenate([lo_n, np.zeros(pad)])
             hip = np.concatenate([hi_n, np.ones(pad)])
-            from jax.experimental import enable_x64
-            with enable_x64():
+            with x64():
                 bs_steps = int(np.ceil(np.log2(n_pad + 1))) + 1
                 starts_r[need, :k_mx] = np.asarray(_jax_solver()(
                     P, netp, n_ap, kkp, lop, hip, k_mx,
@@ -1717,8 +1719,7 @@ def batch_slack_schedule(latencies, energies, counts, deadlines,
         dl_p[:n_b] = dl
         nl_p = np.ones(b_pad, np.int64)
         nl_p[:n_b] = n_lens
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with x64():
             out = _jax_slack_solver()(
                 lat_p, tt_p, kk_p, mvl_p, mvt_p, mvv_p, gate_p, dl_p,
                 nl_p, k_out)

@@ -24,18 +24,32 @@ jit-safety audit (the batched DSE engine traces this module):
   ``gb_ifmap_words is None``, which is static at trace time;
 * every op is an ``xp`` ufunc (``where`` / ``minimum`` / ``floor_divide``),
   so numpy and the jitted jax path produce bit-identical graphs;
-* all quantities are exact in float64: the largest intermediate (layer MACs,
-  ~1e10) is far below 2^53, so ``floor_divide`` on floats is exact and the
-  numpy↔jax parity holds to machine epsilon.
+* all quantities are exact integers in float64: the largest intermediate
+  (layer MACs, ~1e10) is far below 2^53.  The floor/ceil divisions run in
+  integer arithmetic on the device path (:func:`_fdiv`), so the mapping is
+  exact on every backend and the numpy↔jax parity holds to machine
+  epsilon.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict
 
+import numpy as np
+
 
 def _fdiv(xp, a, b):
-    return xp.floor_divide(a, b) if hasattr(xp, "floor_divide") else a // b
+    """Floor division of integer-valued float operands (all below 2^31).
+
+    numpy divides the float64 values, which is exact in IEEE float64.  The
+    device path divides in int32: XLA:TPU emulates float64 as pairs of
+    float32, and there an integer quotient can come out a rounding step
+    low; integer division is exact on every backend."""
+    if xp is np:
+        return np.floor_divide(a, b)
+    q = xp.floor_divide(xp.asarray(a).astype(xp.int32),
+                        xp.asarray(b).astype(xp.int32))
+    return q.astype(xp.float64)
 
 
 def _cdiv(xp, a, b):

@@ -15,33 +15,19 @@ and the streamed per-layer reduction
 ``count_term_layers`` call per fixed-shape chunk — the chunk padding
 upstream keeps ``n_u`` stable so the whole stream shares one trace.
 
-The interpret-mode default can be overridden process-wide with
-``REPRO_PALLAS_NATIVE=1`` (see :func:`default_interpret`) on hosts where
-a native Mosaic/Triton lowering of the tile program has been validated;
-explicit ``interpret=`` arguments always win.
+Interpret mode follows the platform (:func:`repro.kernels.default_interpret`);
+explicit ``interpret=`` arguments win.
 """
 
 from __future__ import annotations
-
-import os
 
 import numpy as np
 import jax.numpy as jnp
 
 from repro.core.energymodel import _PAD_LAYER_ROW
+from repro.kernels import default_interpret
 from .kernel import (CFG_COLUMNS, LAYER_FIELDS, N_TERMS,
                      count_layers_kernel, count_terms_kernel)
-
-
-def default_interpret() -> bool:
-    """Whether the Pallas kernels run in interpret mode by default.
-
-    True everywhere unless ``REPRO_PALLAS_NATIVE=1`` opts into a native
-    lowering — the tile program is float64 with an n_net-wide innermost
-    dimension, which violates TPU/Mosaic tiling constraints as written,
-    so the opt-in is for hosts where a lowering has been validated
-    (see docs/architecture.md)."""
-    return os.environ.get("REPRO_PALLAS_NATIVE", "") != "1"
 
 
 def _pad_operands(cfg_u, lay, block_u: int, block_l: int):
@@ -91,13 +77,11 @@ def count_term_sums(cfg_u, lay, segments, *, block_u: int = 128,
     [n_u, n_net] float64 arrays, drop-in for ``_term_sums_body``'s output
     (config-independent terms arrive broadcast along the unique axis).
 
-    ``interpret=True`` (the default on every platform) runs the Pallas
-    interpreter, still XLA-jitted end to end.  A native lowering is NOT
-    enabled by default: the tile program is float64 (access counts exceed
-    float32's exact-integer range) with an n_net-wide last dimension,
-    both of which violate TPU/Mosaic tiling constraints as written —
-    opting in via ``interpret=False`` is for hosts where a lowering has
-    been validated.
+    ``interpret`` defaults to the platform's choice: the Pallas
+    interpreter (still XLA-jitted end to end) on the CPU, the native
+    lowering elsewhere.  On a TPU Mosaic refuses this float64 tile
+    program (and its float32 variant), so the engine never routes here
+    there (``energymodel.pallas_available``).
     """
     if interpret is None:
         interpret = default_interpret()

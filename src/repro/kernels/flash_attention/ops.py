@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from .kernel import flash_attention_kernel
 
 
@@ -15,7 +16,7 @@ from .kernel import flash_attention_kernel
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: [B, T, H, D]; k, v: [B, S, KV, D] (GQA) → [B, T, H, D].
 
     Repeats are handled by flattening (B, KV, G) into the kernel's BH dim;
@@ -45,7 +46,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     o = flash_attention_kernel(qf, kf, vf, causal=causal, window=window,
                                block_q=block_q, block_kv=block_kv,
-                               kv_len=s, interpret=interpret)
+                               kv_len=s,
+                               interpret=(default_interpret()
+                                          if interpret is None
+                                          else interpret))
     o = o.reshape(b, kvh, g, tt, d).transpose(0, 3, 1, 2, 4)
     o = o.reshape(b, tt, h, d)
     return o[:, :t]

@@ -7,13 +7,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from .kernel import rmsnorm_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",
                                              "interpret"))
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool | None = None) -> jax.Array:
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)
@@ -23,5 +24,6 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out = rmsnorm_kernel(x2, scale, eps=eps, block_rows=block,
-                         interpret=interpret)
+                         interpret=(default_interpret() if interpret is None
+                                    else interpret))
     return out[:n].reshape(shape)

@@ -7,13 +7,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from .kernel import ssd_scan_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, *, chunk: int = 256,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """x: [B, T, H, P]; dt: [B, T, H]; a: [H]; b, c: [B, T, G, N] → y like x.
 
     Groups are broadcast to heads; (B, H) flatten into the kernel grid dim.
@@ -39,6 +40,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     af = jnp.broadcast_to(a[None, :], (bsz, h)).reshape(bsz * h)
 
     y = ssd_scan_kernel(xf, dtf, af, bb, cc, chunk=min(chunk, tt),
-                        interpret=interpret)
+                        interpret=(default_interpret() if interpret is None
+                                   else interpret))
     y = y.reshape(bsz, h, tt, p).transpose(0, 2, 1, 3)
     return y[:, :t]

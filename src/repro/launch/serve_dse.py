@@ -63,7 +63,7 @@ def install_graceful(svc, *, signals=(signal.SIGTERM, signal.SIGINT)):
     return handler
 
 
-def main(argv=None, *, clock=None, sleep=None, grid=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--networks", nargs="*",
                     default=["AlexNet", "VGG16", "MobileNet", "ResNet50"])
@@ -96,23 +96,35 @@ def main(argv=None, *, clock=None, sleep=None, grid=None):
                     help="after draining, run a FULL store scrub "
                     "(audit + quarantine + recompute) and print its "
                     "counters; requires --state-dir")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build_service(args: argparse.Namespace, *, grid=None,
+                  **extra) -> DSEService:
+    """The service the launcher serves from, built from parsed
+    arguments; ``extra`` passes on service keywords (``clock``,
+    ``sleep``)."""
     if grid is None:
         grid = extended_grid() if args.extended else ConfigGrid.product()
     nets = {n: topology.get_network(n) for n in args.networks}
+    return DSEService(grid, nets, max_queue=args.max_queue,
+                      chunk_size=args.chunk_size,
+                      degrade_stride=args.degrade_stride,
+                      backend=args.backend, state_dir=args.state_dir,
+                      verify=not args.no_verify,
+                      verify_fraction=args.verify_fraction,
+                      **extra)
+
+
+def main(argv=None, *, clock=None, sleep=None, grid=None):
+    args = parse_args(argv)
     extra = {}
     if clock is not None:
         extra["clock"] = clock
     if sleep is not None:
         extra["sleep"] = sleep
-    svc = DSEService(grid, nets, max_queue=args.max_queue,
-                     chunk_size=args.chunk_size,
-                     degrade_stride=args.degrade_stride,
-                     backend=args.backend, state_dir=args.state_dir,
-                     verify=not args.no_verify,
-                     verify_fraction=args.verify_fraction,
-                     **extra)
+    svc = build_service(args, grid=grid, **extra)
+    grid = svc.grid
     prev_handlers = {s: signal.getsignal(s)
                      for s in (signal.SIGTERM, signal.SIGINT)}
     install_graceful(svc)
@@ -121,7 +133,7 @@ def main(argv=None, *, clock=None, sleep=None, grid=None):
               f"from {args.state_dir}")
 
     rng = np.random.default_rng(args.seed)
-    names = list(nets)
+    names = list(svc.names)
     rejected = 0
     for _ in range(args.requests):
         kind = KINDS[int(rng.integers(len(KINDS)))]
@@ -196,4 +208,6 @@ def main(argv=None, *, clock=None, sleep=None, grid=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

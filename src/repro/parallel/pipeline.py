@@ -23,10 +23,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map                      # jax >= 0.6
-except ImportError:                                # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.partition import Partition, bb_partition
@@ -153,14 +149,8 @@ def pipeline_forward(staged_params, mask, x_micro, *, mesh: Mesh,
 
     pspecs_params = jax.tree.map(lambda _: P(stage_axis), staged_params)
     batch_spec = P(None, data_axes if data_axes else None)
-    try:
-        fn = shard_map(
-            per_stage, mesh=mesh,
-            in_specs=(pspecs_params, P(stage_axis), batch_spec),
-            out_specs=batch_spec, check_vma=False)
-    except TypeError:                                  # older jax
-        fn = shard_map(
-            per_stage, mesh=mesh,
-            in_specs=(pspecs_params, P(stage_axis), batch_spec),
-            out_specs=batch_spec, check_rep=False)
+    fn = jax.shard_map(
+        per_stage, mesh=mesh,
+        in_specs=(pspecs_params, P(stage_axis), batch_spec),
+        out_specs=batch_spec, check_vma=False)
     return fn(staged_params, mask, x_micro)

@@ -43,7 +43,9 @@ Scenarios that kill every core come back ``feasible=False`` per network
    ``degraded=True``.
 3. *Retry with exponential backoff*: transient backend failures re-run the
    computation after ``backoff_s · 2^attempt``, walking down the engine's
-   pallas → jax → numpy fallback chain after repeated failures.
+   pallas → jax → numpy fallback chain after repeated failures.  Off the
+   CPU the chain ends at the device path: a fault that outlasts the
+   retries raises :class:`ServiceFault` rather than answer from numpy.
 4. *Checkpoint/resume*: every streamed sweep exports its
    :class:`repro.core.energymodel.StreamFoldState` after each chunk; a
    retry resumes from the last folded chunk instead of restarting, and a
@@ -210,6 +212,7 @@ class DSEService:
         self.responses: List[DSEResponse] = []
         self._next_rid = 0
         self._t0 = self._clock()
+        self._last_fault: Optional[str] = None
         # tier ("exact"|"sub") × metric caches
         self._streams: Dict[Tuple[str, str], energymodel.LayerTopK] = {}
         self._points: Dict[Tuple[str, str], tuple] = {}
@@ -423,19 +426,31 @@ class DSEService:
 
     # -- retry / backoff / resume core ------------------------------------
     def _backend_ladder(self) -> List[str | None]:
+        """Backends a faulting stream retries on, in order.  Off the CPU
+        the ladder stops at the device path: a host rung would answer
+        from numpy and hide a faulting device behind a correct result."""
         resolved = energymodel.resolve_backend(self.backend)
         chain = list(energymodel.BACKENDS)
-        return chain[chain.index(resolved):] or ["numpy"]
+        chain = chain[chain.index(resolved):]
+        if energymodel.platform() != "cpu" and resolved != "numpy":
+            chain.remove("numpy")
+        return chain
 
     def _with_retries(self, run, *, key: tuple,
                       budget_end: Optional[float]):
         """``run(backend, resume_from)`` with exponential backoff, backend
-        fallback, and checkpoint-resume.  ``_BudgetExhausted`` (raised by
-        the budget watchdog inside ``run``) propagates — it is a deadline,
-        not a fault."""
+        fallback, and checkpoint-resume.  The ladder steps to the next
+        backend only when a retry fails again without folding a chunk
+        past the previous failure: faults on different chunks, each
+        recovered by its retry, keep the stream on its backend (and its
+        answer bit-identical to a fault-free run).  ``_BudgetExhausted``
+        (raised by the budget watchdog inside ``run``) propagates — it is
+        a deadline, not a fault."""
         ladder = self._backend_ladder()
         bi = 0
         attempt = 0
+        stalled = 0            # consecutive faults at one fold position
+        fault_pos = None
         while True:
             resume = self._ckpt.get(key)
             if resume is not None:
@@ -452,12 +467,17 @@ class DSEService:
                 attempt += 1
             except Exception as e:
                 self.stats["faults"] += 1
+                self._last_fault = f"{key}: {type(e).__name__}: {e}"
                 attempt += 1
                 if attempt > self.max_retries:
                     raise ServiceFault(
                         f"{key} failed after {attempt} attempts across "
                         f"backends {ladder[:bi + 1]}: {e}") from e
-                if attempt >= 2 and bi + 1 < len(ladder):
+                ck = self._ckpt.get(key)
+                pos = None if ck is None else ck.next_chunk
+                stalled = stalled + 1 if pos == fault_pos else 1
+                fault_pos = pos
+                if stalled >= 2 and bi + 1 < len(ladder):
                     bi += 1
                     self.stats["backend_fallbacks"] += 1
                 delay = self.backoff_s * (2.0 ** (attempt - 1))
@@ -1167,4 +1187,5 @@ class DSEService:
             lat_window=self.lat_window,
             state_dir=self.state_dir,
             store=None if self.store is None else self.store.health(),
+            last_fault=self._last_fault,
             **self.stats)

@@ -31,8 +31,7 @@ def _kernel_inputs(grid, networks):
 
 
 def _pallas_vs_ref(cfg_u, lay, segments, rtol=1e-12):
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with energymodel.x64():
         ref = np.asarray(count_term_sums_ref(cfg_u, lay, segments))
         out = np.stack([np.asarray(o)
                         for o in count_term_sums(cfg_u, lay, segments)])
@@ -58,12 +57,11 @@ def test_pallas_matches_ref_odd_blocks(networks):
 def test_per_layer_kernel_matches_ref(networks):
     """The segment-matmul-free per-layer variant ≡ the raw [14, n_u, L]
     term stack, and summing its segments reproduces count_term_sums."""
-    from jax.experimental import enable_x64
     cfg_u, lay, segments = _kernel_inputs(
         accelerator.ConfigGrid.product(
             arrays=((12, 14), (16, 16), (64, 64)),
             gb_psum_kb=(13, 54, 216), gb_ifmap_kb=(27,)), networks)
-    with enable_x64():
+    with energymodel.x64():
         ref = np.asarray(count_term_layers_ref(cfg_u, lay))
         out = np.stack([np.asarray(o)
                         for o in count_term_layers(cfg_u, lay)])
@@ -78,12 +76,11 @@ def test_per_layer_kernel_matches_ref(networks):
 
 def test_per_layer_kernel_odd_blocks(networks):
     """Layer/row paddings of the per-layer kernel slice off cleanly."""
-    from jax.experimental import enable_x64
     grid = accelerator.ConfigGrid.product(
         arrays=((16, 16),), gb_psum_kb=(13, 27, 54), gb_ifmap_kb=(27, 54))
     cfg_u, lay, _ = _kernel_inputs(grid, {"AlexNet":
                                           networks["AlexNet"]})
-    with enable_x64():
+    with energymodel.x64():
         ref = np.asarray(count_term_layers_ref(cfg_u, lay))
         out = np.stack([np.asarray(o)
                         for o in count_term_layers(cfg_u, lay,
@@ -138,6 +135,33 @@ def test_backend_resolution_and_fallback(monkeypatch):
     monkeypatch.setattr(energymodel, "jax_available", lambda: False)
     assert energymodel.resolve_backend("pallas") == "numpy"
     assert energymodel.resolve_backend(None) == "numpy"
+
+
+def test_tpu_platform_refuses_pallas_and_never_interprets(networks,
+                                                         monkeypatch):
+    """On a TPU the device path is jax: the Pallas kernel is not reported
+    runnable, an explicit request raises (naming the 64-bit reason)
+    before any kernel is traced or dispatched, and nothing interprets."""
+    from repro.kernels import default_interpret
+    assert default_interpret() is True                 # the CPU here
+    monkeypatch.setattr(energymodel, "platform", lambda: "tpu")
+    assert default_interpret() is False
+    assert not energymodel.pallas_available()
+    assert energymodel.resolve_backend(None) == "jax"
+    assert energymodel.resolve_backend("jax") == "jax"
+    before = energymodel.jit_cache_stats()
+    grid = accelerator.ConfigGrid.product(arrays=((16, 16),),
+                                          gb_psum_kb=(13,),
+                                          gb_ifmap_kb=(27,))
+    for call in (
+            lambda: energymodel.evaluate_networks(grid, networks,
+                                                  backend="pallas"),
+            lambda: energymodel.stream_layer_topk(grid, networks,
+                                                  backend="pallas")):
+        with pytest.raises(energymodel.BackendUnavailable,
+                           match="64-bit"):
+            call()
+    assert energymodel.jit_cache_stats() == before
 
 
 def test_kernel_column_orders_match_engine():

@@ -199,3 +199,40 @@ def test_submit_validates_inputs(grid, networks):
         svc.submit("best_config", network="NotANet")
     with pytest.raises(ValueError):
         svc.submit("pareto")                      # needs a network
+
+
+def test_backend_ladder_ends_at_the_device_path(grid, networks,
+                                                monkeypatch):
+    """On the CPU a faulting stream may fall back to numpy; on a TPU the
+    ladder has no host rung, so exhausted retries raise ServiceFault
+    instead of answering from numpy."""
+    from repro.serving.dse_service import ServiceFault
+    ran = []
+
+    def always_fails(backend, resume):
+        ran.append(backend)
+        raise RuntimeError("device fault")
+
+    def exhaust():
+        svc = DSEService(grid, networks, chunk_size=5, max_retries=2,
+                         backoff_s=0.0, verify=False)
+        ran.clear()
+        with pytest.raises(ServiceFault):
+            svc._with_retries(always_fails, key=("exact", "edp"),
+                              budget_end=None)
+        assert svc.health()["last_fault"] == (
+            "('exact', 'edp'): RuntimeError: device fault")
+        return svc._backend_ladder(), list(ran), svc.stats
+
+    ladder, backends, stats = exhaust()
+    assert ladder == ["jax", "numpy"]
+    assert backends == ["jax", "jax", "numpy"]
+    assert stats["backend_fallbacks"] == 1
+
+    monkeypatch.setattr(energymodel, "platform", lambda: "tpu")
+    ladder, backends, stats = exhaust()
+    assert ladder == ["jax"]
+    assert backends == ["jax"] * 3
+    assert stats["backend_fallbacks"] == 0
+    assert DSEService(grid, networks,
+                      backend="numpy")._backend_ladder() == ["numpy"]

@@ -5,6 +5,8 @@ rows), and `co_design_streaming == co_design` parity on small grids
 (every backend × chunked × sharded) and on the extended 5,400-point
 space."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -303,3 +305,26 @@ def test_pareto_codesign_streaming_vs_dense_problems(networks, grid):
     np.testing.assert_array_equal(pd_.best_chip, ps.best_chip)
     np.testing.assert_array_equal(pd_.net_frontier, ps.net_frontier)
     np.testing.assert_allclose(pd_.scores, ps.scores, rtol=1e-9)
+
+
+def test_pool_ignores_fold_rounding_of_the_minima():
+    """On a TPU the fold's ``min_metric``/``topk_metric`` come from
+    emulated float64, a rounding step away from the host's ``e * t`` of
+    the same boundary rows.  Every network's argmin ties at relative
+    metric 1.0 in the pool top-up, so the pool must not depend on which
+    way each minimum rounded."""
+    egrid = accelerator.extended_grid()
+    nets = {n: topology.get_network(n) for n in topology.NETWORKS}
+    st = energymodel.stream_layer_topk(egrid, nets, topk=6, bound=0.05,
+                                       metric="edp", chunk_size=2048,
+                                       backend="numpy")
+    ref = hetero.codesign_problems_streaming(
+        egrid, nets, 4, max_types=3, pool_size=6, stream=st).pool
+    j = np.arange(len(nets))
+    step = 1.0 + np.where(j % 2, 1, -1) * (j + 1) * 2.0 ** -52
+    rounded = dataclasses.replace(st, min_metric=st.min_metric * step,
+                                  topk_metric=st.topk_metric * step)
+    assert not np.array_equal(rounded.min_metric, st.min_metric)
+    got = hetero.codesign_problems_streaming(
+        egrid, nets, 4, max_types=3, pool_size=6, stream=rounded).pool
+    assert got == ref

@@ -206,6 +206,36 @@ def test_boundary_hit_below_min_caught():
     assert ei.value.invariant == "boundary_bound"
 
 
+@pytest.mark.parametrize("backend,rel,caught", [
+    ("numpy", 1e-15, True),     # same arithmetic: exact bound
+    ("jax", 1e-15, False),      # device rounding of e*t: tolerated
+    ("jax", 1e-9, True),        # a real miss: caught on every backend
+])
+def test_boundary_below_min_tolerance_follows_backend(backend, rel, caught):
+    """The host re-derives a hit's metric in IEEE float64; the fold's
+    minimum came from the backend's own arithmetic (emulated float64 on
+    a TPU).  A hit a rounding step below the minimum is that rounding on
+    a device backend, and a missed update on the numpy one."""
+    v = StreamVerifier(verify_fraction=0.0)
+    v.bind(kind="layer_topk", names=NAMES, metric="edp", topk=2,
+           bound=0.1, backend=backend)
+    st = _layer_state()
+    es = np.array([[4.0 * (1.0 - rel), 2.0]])     # min_m is 4 for NetA
+    ts = np.array([[1.0, 1.0]])
+    mask = np.array([[True, False]])
+    cand = {"NetA": [(np.array([2]), es[0, :1], ts[0, :1])], "NetB": []}
+    checks = (lambda: _fold(v, st, [np.array(a, copy=True) for a in st],
+                            es=es, ts=ts, mask=mask),
+              lambda: v.check_resume(st, cand))
+    for check in checks:
+        if caught:
+            with pytest.raises(FoldInvariantError) as ei:
+                check()
+            assert ei.value.invariant == "boundary_bound"
+        else:
+            check()
+
+
 def test_resume_state_nan_caught():
     v = _verifier()
     st = _layer_state()
