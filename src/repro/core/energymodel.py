@@ -70,7 +70,7 @@ from typing import Any, Dict, List, Mapping, Sequence, Tuple
 import numpy as np
 
 from .accelerator import AcceleratorConfig, ConfigGrid
-from . import rs_mapping
+from . import obs, rs_mapping
 from .topology import Layer
 
 _POOL_OP_ENERGY = 0.2      # a pooling compare/add relative to a MAC
@@ -355,14 +355,21 @@ def simulate_network(cfg: AcceleratorConfig, layers: Sequence[Layer],
 
 _LAYER_BUCKET = 256
 
-#: Compile/trace statistics of the module-level kernel — ``traces`` counts
-#: actual retraces, ``calls`` every dispatch; a warm engine has
-#: calls ≫ traces.  (Read via :func:`jit_cache_stats`.)
-_JIT_STATS = {"traces": 0, "calls": 0}
+#: The engine's programs: the grid kernel in its four forms and the two
+#: streamed folds (every other program counts under its own name too).
+_ENGINE_PROGRAMS = ("grid_kernel", "grid_kernel_layers",
+                    "grid_kernel_sharded", "grid_kernel_sharded_layers",
+                    "stream_fold", "layer_fold")
 
 
 def jit_cache_stats() -> Dict[str, int]:
-    return dict(_JIT_STATS)
+    """Traces and dispatches of the engine's programs since the process
+    started: ``traces`` counts actual retraces, ``calls`` every dispatch;
+    a warm engine has calls ≫ traces.  Per program they are the
+    ``jit.trace.<name>`` / ``jit.call.<name>`` totals of :mod:`obs`."""
+    return dict(
+        traces=sum(obs.total(f"jit.trace.{p}") for p in _ENGINE_PROGRAMS),
+        calls=sum(obs.total(f"jit.call.{p}") for p in _ENGINE_PROGRAMS))
 
 
 def x64():
@@ -702,16 +709,16 @@ _jitted_grid_kernels: Dict[Tuple[str, bool], Any] = {}  # per (backend, mode)
 def _jax_grid_kernel(backend: str = "jax", per_layer: bool = False):
     key = (backend, per_layer)
     if key not in _jitted_grid_kernels:
-        import jax
         import jax.numpy as jnp
 
         def kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs):
-            _JIT_STATS["traces"] += 1        # runs only while tracing
             return _grid_kernel_body(jnp, segments, cfg_m, cfg_u, lay,
                                      inv_m, inv, coefs, backend=backend,
                                      per_layer=per_layer)
 
-        _jitted_grid_kernels[key] = jax.jit(kernel, static_argnums=0)
+        name = "grid_kernel_layers" if per_layer else "grid_kernel"
+        _jitted_grid_kernels[key] = obs.Program(name, kernel,
+                                                static_argnums=0)
     return _jitted_grid_kernels[key]
 
 
@@ -731,8 +738,6 @@ def _jax_sharded_kernel(backend: str = "jax", per_layer: bool = False):
     gather/combine.  Explicit specs — GSPMD's auto-partitioning of the
     same program chooses badly on CPU meshes."""
     global _jitted_sharded_kernels, _sharded_kernel_ndev
-    import jax
-
     mesh = _cfg_mesh()
     if _sharded_kernel_ndev != mesh.devices.size:
         _jitted_sharded_kernels = {}         # device count changed: rebuild
@@ -740,12 +745,14 @@ def _jax_sharded_kernel(backend: str = "jax", per_layer: bool = False):
     key = (backend, per_layer)
     if key not in _jitted_sharded_kernels:
         def kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs):
-            _JIT_STATS["traces"] += 1        # runs only while tracing
             return _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m,
                                       inv, coefs, backend=backend,
                                       per_layer=per_layer)
 
-        _jitted_sharded_kernels[key] = jax.jit(kernel, static_argnums=0)
+        name = ("grid_kernel_sharded_layers" if per_layer
+                else "grid_kernel_sharded")
+        _jitted_sharded_kernels[key] = obs.Program(name, kernel,
+                                                   static_argnums=0)
     return _jitted_sharded_kernels[key]
 
 
@@ -1049,9 +1056,7 @@ def _eval_fields(fields, lay, segments, backend: str, shard: bool,
             kern = _jax_sharded_kernel(backend, per_layer)
         else:
             kern = _jax_grid_kernel(backend, per_layer)
-        _JIT_STATS["calls"] += 1
-        e, t = kern(segments, *args)
-        return np.asarray(e), np.asarray(t)
+        return obs.fetch("dse.eval.wait", *kern(segments, *args))
 
 
 def _dispatch_chunk(fc, lay, segments, device=None, backend: str = "jax",
@@ -1060,13 +1065,16 @@ def _dispatch_chunk(fc, lay, segments, device=None, backend: str = "jax",
     uncollected device arrays so the host can prepare the next chunk — and
     other devices can compute — while this one runs."""
     import jax
-    cfg_m, cfg_u, inv_m, inv, coefs = _prepare_fields(
-        fc, _UNIQUE_BUCKET, _MAPPING_BUCKET, backend=backend)
-    args = (cfg_m, cfg_u, lay, inv_m, inv, coefs)
-    if device is not None:
-        args = jax.device_put(args, device)
-    _JIT_STATS["calls"] += 1
-    return _jax_grid_kernel(backend, per_layer)(segments, *args)
+    with obs.span("dse.stream.prep"):
+        cfg_m, cfg_u, inv_m, inv, coefs = _prepare_fields(
+            fc, _UNIQUE_BUCKET, _MAPPING_BUCKET, backend=backend)
+    obs.count("dse.stream.rows_unique", int(inv.max()) + 1)
+    obs.count("dse.stream.rows_padded", inv_m.shape[0])
+    with obs.span("dse.stream.dispatch"):
+        args = (cfg_m, cfg_u, lay, inv_m, inv, coefs)
+        if device is not None:
+            args = jax.device_put(args, device)
+        return _jax_grid_kernel(backend, per_layer)(segments, *args)
 
 
 def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
@@ -1484,15 +1492,14 @@ def _jax_reduce_step():
     mask, and the masked candidate rows ever leave it."""
     global _jitted_reduce_step
     if _jitted_reduce_step is None:
-        import jax
         import jax.numpy as jnp
 
         def red(metric, topk, e, t, state, base, m_valid, bound):
-            _JIT_STATS["traces"] += 1        # runs only while tracing
             return _stream_reduce_body(jnp, metric, topk, e, t, base,
                                        m_valid, bound, state)
 
-        _jitted_reduce_step = jax.jit(red, static_argnums=(0, 1))
+        _jitted_reduce_step = obs.Program("stream_fold", red,
+                                          static_argnums=(0, 1))
     return _jitted_reduce_step
 
 
@@ -1630,7 +1637,6 @@ def stream_networks(grid: ConfigGrid,
                     _guard_chunk(ci, start, stop, e_d, t_d, names)
                 if verify is not None:  # raises BEFORE the fold commits
                     verify.check_chunk(ci, start, stop, fc, e_d, t_d)
-                _JIT_STATS["calls"] += 1
                 new_state, mask = _jax_reduce_step()(
                     metric, topk, e_d, t_d, state, np.int64(start),
                     np.int64(stop - start), float(bound))
@@ -1812,19 +1818,19 @@ def _jax_layer_reduce_step():
     ever cross to the host."""
     global _jitted_layer_reduce
     if _jitted_layer_reduce is None:
-        import jax
+        import jax.numpy as jnp
 
         def red(metric, topk, e, t, state, base, m_valid, bound,
                 lay_valid):
-            _JIT_STATS["traces"] += 1        # runs only while tracing
-            import jax.numpy as jnp
             return _layer_reduce_body(jnp, metric, topk, e, t, base,
                                       m_valid, bound, lay_valid, state)
 
-        _jitted_layer_reduce = jax.jit(red, static_argnums=(0, 1))
+        _jitted_layer_reduce = obs.Program("layer_fold", red,
+                                           static_argnums=(0, 1))
     return _jitted_layer_reduce
 
 
+@obs.traced("dse.stream")
 def stream_layer_topk(grid: ConfigGrid,
                       networks: Mapping[str, Sequence[Layer]],
                       *,
@@ -1870,33 +1876,34 @@ def stream_layer_topk(grid: ConfigGrid,
     _LAST_BACKEND = backend
     names = tuple(networks)
     n_net = len(names)
-    lay, segments = _stack_networks(networks, absorb_pad=False)
-    lay = {k: v[None, :] for k, v in lay.items()}
-    n_layer = _layer_axis_len(segments)
-    fields = grid.fields if isinstance(grid, ConfigGrid) else dict(grid)
-    n = int(next(iter(fields.values())).shape[0])
-    chunk = max(1, min(chunk_size, n))
-    lay_counts = network_layer_counts(networks)
-    lay_valid = np.arange(n_layer)[None, :] < lay_counts[:, None]
+    with obs.span("dse.stream.prep"):
+        lay, segments = _stack_networks(networks, absorb_pad=False)
+        lay = {k: v[None, :] for k, v in lay.items()}
+        n_layer = _layer_axis_len(segments)
+        fields = grid.fields if isinstance(grid, ConfigGrid) else dict(grid)
+        n = int(next(iter(fields.values())).shape[0])
+        chunk = max(1, min(chunk_size, n))
+        lay_counts = network_layer_counts(networks)
+        lay_valid = np.arange(n_layer)[None, :] < lay_counts[:, None]
 
-    k = int(topk)
-    state = (np.full((k, n_net), np.inf),              # top_v
-             np.full((k, n_net), -1, np.int64),        # top_i
-             np.zeros((k, n_net, n_layer)),            # top_e
-             np.zeros((k, n_net, n_layer)),            # top_t
-             np.full(n_net, np.inf),                   # min_energy
-             np.full(n_net, np.inf),                   # min_latency
-             np.full(n_net, np.inf),                   # min_edp
-             np.full(n_net, np.inf),                   # min_metric
-             np.full(n_net, -1, np.int64),             # argmin
-             np.full((n_net, n_layer), np.inf),        # layer_min_metric
-             np.full((n_net, n_layer), -1, np.int64))  # layer_argmin
-    b = 0.0 if bound is None else float(bound)
-    cand: Dict[str, list] = {nm: [] for nm in names}
-    n_chunks = -(-n // chunk)
-    ihash = stream_input_hash(fields, networks, kind="layer_topk",
-                              metric=metric, bound=bound, topk=k,
-                              chunk=chunk)
+        k = int(topk)
+        state = (np.full((k, n_net), np.inf),              # top_v
+                 np.full((k, n_net), -1, np.int64),        # top_i
+                 np.zeros((k, n_net, n_layer)),            # top_e
+                 np.zeros((k, n_net, n_layer)),            # top_t
+                 np.full(n_net, np.inf),                   # min_energy
+                 np.full(n_net, np.inf),                   # min_latency
+                 np.full(n_net, np.inf),                   # min_edp
+                 np.full(n_net, np.inf),                   # min_metric
+                 np.full(n_net, -1, np.int64),             # argmin
+                 np.full((n_net, n_layer), np.inf),        # layer_min_metric
+                 np.full((n_net, n_layer), -1, np.int64))  # layer_argmin
+        b = 0.0 if bound is None else float(bound)
+        cand: Dict[str, list] = {nm: [] for nm in names}
+        n_chunks = -(-n // chunk)
+        ihash = stream_input_hash(fields, networks, kind="layer_topk",
+                                  metric=metric, bound=bound, topk=k,
+                                  chunk=chunk)
     done = 0
     if resume_from is not None:
         state, cand, done = _resume_fold(resume_from, kind="layer_topk",
@@ -1937,8 +1944,9 @@ def stream_layer_topk(grid: ConfigGrid,
             if ci < done:
                 continue
             stop = min(start + chunk, n)
-            fc = {k_: _pad_rows(v[start:stop], chunk)
-                  for k_, v in fields.items()}
+            with obs.span("dse.stream.prep"):
+                fc = {k_: _pad_rows(v[start:stop], chunk)
+                      for k_, v in fields.items()}
             yield ci, start, stop, fc
 
     if backend == "numpy":
@@ -1970,26 +1978,31 @@ def stream_layer_topk(grid: ConfigGrid,
             def reduce_one(item):
                 nonlocal state
                 ci, start, stop, e_d, t_d, fc = item
-                if n_dev > 1:
-                    e_d = jax.device_put(e_d, devs[0])
-                    t_d = jax.device_put(t_d, devs[0])
-                e_d, t_d = _apply_chunk_hook(ci, e_d, t_d)
-                _JIT_STATS["calls"] += 1
-                new_state, mask, es, ts = _jax_layer_reduce_step()(
-                    metric, k, e_d, t_d, state, np.int64(start),
-                    np.int64(stop - start), float(b), lay_valid)
-                if nan_guard:     # raises BEFORE the fold commits
-                    _guard_chunk(ci, start, stop, es, ts, names)
-                if verify is not None:
-                    verify.check_chunk(ci, start, stop, fc,
-                                       np.asarray(e_d), np.asarray(t_d))
-                    verify.check_fold(ci, start, stop, state, new_state,
-                                      es=np.asarray(es),
-                                      ts=np.asarray(ts),
-                                      mask=np.asarray(mask))
-                state = new_state
-                collect(mask, es, ts, start)
-                emit(ci)
+                with obs.span("dse.stream.dispatch"):
+                    if n_dev > 1:
+                        e_d = jax.device_put(e_d, devs[0])
+                        t_d = jax.device_put(t_d, devs[0])
+                    e_d, t_d = _apply_chunk_hook(ci, e_d, t_d)
+                    new_state, mask, es, ts = _jax_layer_reduce_step()(
+                        metric, k, e_d, t_d, state, np.int64(start),
+                        np.int64(stop - start), float(b), lay_valid)
+                if nan_guard or bound is not None:   # the host reads them
+                    es, ts, mask = obs.fetch("dse.stream.wait", es, ts,
+                                             mask)
+                with obs.span("dse.stream.collect"):
+                    if nan_guard:     # raises BEFORE the fold commits
+                        _guard_chunk(ci, start, stop, es, ts, names)
+                    if verify is not None:
+                        verify.check_chunk(ci, start, stop, fc,
+                                           np.asarray(e_d),
+                                           np.asarray(t_d))
+                        verify.check_fold(ci, start, stop, state,
+                                          new_state, es=np.asarray(es),
+                                          ts=np.asarray(ts),
+                                          mask=np.asarray(mask))
+                    state = new_state
+                    collect(mask, es, ts, start)
+                    emit(ci)
 
             for ci, start, stop, fc in chunks():
                 dev = devs[ci % n_dev] if n_dev > 1 else None
@@ -2001,25 +2014,29 @@ def stream_layer_topk(grid: ConfigGrid,
                     reduce_one(pending.pop(0))
             for item in pending:
                 reduce_one(item)
+        state = obs.fetch("dse.stream.wait", *state)
 
     (top_v, top_i, top_e, top_t, min_e, min_t, min_edp, min_m, argm,
      lmin, larg) = (np.asarray(s) for s in state)
 
-    b_idx = b_e = b_t = None
-    if bound is not None:
-        b_idx, b_e, b_t = {}, {}, {}
-        for j, nm in enumerate(names):
-            if cand[nm]:
-                idx = np.concatenate([c[0] for c in cand[nm]])
-                ee = np.concatenate([c[1] for c in cand[nm]])
-                tt = np.concatenate([c[2] for c in cand[nm]])
-            else:                                      # pragma: no cover
-                idx, ee, tt = (np.zeros(0, np.int64),) + (np.zeros(0),) * 2
-            v = _metric_of(metric, ee, tt)
-            keep = v <= min_m[j] * (1.0 + b)   # prune to the final min
-            idx, ee, tt, v = idx[keep], ee[keep], tt[keep], v[keep]
-            order = np.lexsort((idx, v))       # metric, then lower index
-            b_idx[nm], b_e[nm], b_t[nm] = idx[order], ee[order], tt[order]
+    with obs.span("dse.stream.collect"):
+        b_idx = b_e = b_t = None
+        if bound is not None:
+            b_idx, b_e, b_t = {}, {}, {}
+            for j, nm in enumerate(names):
+                if cand[nm]:
+                    idx = np.concatenate([c[0] for c in cand[nm]])
+                    ee = np.concatenate([c[1] for c in cand[nm]])
+                    tt = np.concatenate([c[2] for c in cand[nm]])
+                else:                                  # pragma: no cover
+                    idx, ee, tt = ((np.zeros(0, np.int64),)
+                                   + (np.zeros(0),) * 2)
+                v = _metric_of(metric, ee, tt)
+                keep = v <= min_m[j] * (1.0 + b)   # prune to the final min
+                idx, ee, tt, v = idx[keep], ee[keep], tt[keep], v[keep]
+                order = np.lexsort((idx, v))   # metric, then lower index
+                b_idx[nm], b_e[nm], b_t[nm] = (idx[order], ee[order],
+                                               tt[order])
 
     return LayerTopK(
         networks=names, n_cfg=n, metric=metric,

@@ -56,6 +56,7 @@ from typing import Dict, List, Mapping, Sequence, Tuple
 import numpy as np
 
 from . import energymodel
+from . import obs
 from . import partition
 from .accelerator import ConfigGrid, GRID_COLUMNS
 from .dse import SweepResult, boundary_configs
@@ -399,9 +400,10 @@ def _problems_from_pool(grid: ConfigGrid,
     reference minima (``refs``) differ between them."""
     names = list(networks)
     n_net = len(names)
-    e_l, t_l = energymodel.evaluate_networks(
-        grid.take(pool), networks, use_jax=use_jax, backend=backend,
-        per_layer=True)                                   # [P, n_net, L]
+    with obs.span("dse.pool.eval"):
+        e_l, t_l = energymodel.evaluate_networks(
+            grid.take(pool), networks, use_jax=use_jax, backend=backend,
+            per_layer=True)                               # [P, n_net, L]
     lens = energymodel.network_layer_counts(networks)
 
     chips = _enumerate_chips(len(pool), max_types, m_cores)
@@ -449,6 +451,7 @@ def codesign_problems(grid: ConfigGrid,
                                refs, backend, use_jax)
 
 
+@obs.traced("dse.pool")
 def codesign_problems_streaming(grid: ConfigGrid,
                                 networks: Mapping[str, Sequence[Layer]],
                                 m_cores: int = 4,
@@ -567,6 +570,7 @@ def codesign_problems_streaming(grid: ConfigGrid,
                                refs, backend, use_jax)
 
 
+@obs.traced("dse.codesign")
 def co_design(grid: ConfigGrid,
               networks: Mapping[str, Sequence[Layer]],
               m_cores: int = 4,
@@ -608,6 +612,7 @@ def co_design(grid: ConfigGrid,
     return score_codesign(probs, res, metric=metric, m_cores=m_cores)
 
 
+@obs.traced("dse.codesign")
 def co_design_streaming(grid: ConfigGrid,
                         networks: Mapping[str, Sequence[Layer]],
                         m_cores: int = 4,
@@ -657,6 +662,7 @@ def _scheduled_energy(probs: CoDesignProblems,
         en_b, tt[:, None, :], axis=1)[:, 0, :].sum(-1)    # [B]
 
 
+@obs.traced("dse.score")
 def score_codesign(probs: CoDesignProblems,
                    res: "partition.BatchHeteroResult",
                    *, metric: str = "edp", m_cores: int = 4,

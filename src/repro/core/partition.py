@@ -59,6 +59,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from . import obs
 from .energymodel import _bucketed, jax_available, x64
 
 
@@ -286,7 +287,6 @@ def _jax_solver():
     device dispatch instead of thousands of tiny numpy ops."""
     global _jitted_solver
     if _jitted_solver is None:
-        import jax
         import jax.numpy as jnp
         from jax import lax
 
@@ -332,7 +332,8 @@ def _jax_solver():
                 pos = jnp.where((s < kk) & (pos < n_arr), j, pos)
             return jnp.stack(starts, axis=1)
 
-        _jitted_solver = jax.jit(solve, static_argnums=(6, 7))
+        _jitted_solver = obs.Program("partition_solve", solve,
+                                     static_argnums=(6, 7))
     return _jitted_solver
 
 
@@ -495,14 +496,13 @@ _jitted_pareto = None
 def _jax_pareto():
     global _jitted_pareto
     if _jitted_pareto is None:
-        import jax
         import jax.numpy as jnp
 
         def kernel(value, latency, norm_latency, deadlines, n_net):
             return _pareto_body(jnp, value, latency, norm_latency,
                                 deadlines, n_net)
 
-        _jitted_pareto = jax.jit(kernel)
+        _jitted_pareto = obs.Program("pareto_scores", kernel)
     return _jitted_pareto
 
 
@@ -699,7 +699,6 @@ def _jax_hetero_stage1():
     first-minimum argmin, same cumsum order; adding exact zeros)."""
     global _jitted_hetero_stage1
     if _jitted_hetero_stage1 is None:
-        import jax
         import jax.numpy as jnp
 
         def stage1(lat, avail, n_lens):
@@ -718,10 +717,11 @@ def _jax_hetero_stage1():
             return (masked, jnp.where(valid, tt, 0),
                     tmask.sum(axis=-1), masked.max(axis=-1))
 
-        _jitted_hetero_stage1 = jax.jit(stage1)
+        _jitted_hetero_stage1 = obs.Program("schedule_stage1", stage1)
     return _jitted_hetero_stage1
 
 
+@obs.traced("dse.schedule")
 def batch_schedule_hetero(latencies, counts,
                           n_layers=None,
                           use_jax: bool | None = None,
@@ -852,9 +852,9 @@ def batch_schedule_hetero(latencies, counts,
     valid_l = l_idx[None, :] < n_lens_p[:, None]              # [B, L]
     if use_jax:
         with x64():
-            masked, tt, n_t, mx = (
-                np.asarray(o) for o in _jax_hetero_stage1()(
-                    lat, avail, n_lens_p))
+            masked, tt, n_t, mx = obs.fetch(
+                "dse.schedule.wait",
+                *_jax_hetero_stage1()(lat, avail, n_lens_p))
     else:
         cost = np.where(avail[:, :, None], lat, np.inf)
         tt = np.argmin(cost, axis=1)                          # [B, L]
@@ -955,9 +955,10 @@ def batch_schedule_hetero(latencies, counts,
             hip = np.concatenate([hi_n, np.ones(pad)])
             with x64():
                 bs_steps = int(np.ceil(np.log2(n_pad + 1))) + 1
-                starts_r[need, :k_mx] = np.asarray(_jax_solver()(
-                    P, netp, n_ap, kkp, lop, hip, k_mx,
-                    bs_steps))[:need.size]
+                starts_r[need, :k_mx] = obs.fetch(
+                    "dse.schedule.wait", _jax_solver()(
+                        P, netp, n_ap, kkp, lop, hip, k_mx,
+                        bs_steps))[0][:need.size]
         else:
             lo_b, hi_b = lo_n.copy(), hi_n.copy()
             for _ in range(_BISECT_ITERS):
@@ -1464,7 +1465,8 @@ def _jax_slack_solver():
             bott = loads.max(axis=(-1, -2))
             return tt, n_moves, starts, loads, seg + 1, bott
 
-        _jitted_slack = jax.jit(solve, static_argnums=(9,))
+        _jitted_slack = obs.Program("slack_solve", solve,
+                                    static_argnums=(9,))
     return _jitted_slack
 
 
