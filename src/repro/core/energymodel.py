@@ -522,11 +522,32 @@ _MAPPING_COLUMNS = ("rows", "cols", "gb_ifmap_words",
 _MAPPING_KEYS = ("n_c", "n_m", "n_oy", "w_psum", "ky_serial", "active_pes")
 
 
+#: A partial row key is compacted to dense ranks before its radix product
+#: would reach this, so the int64 key never overflows.
+_KEY_SPAN_LIMIT = 1 << 62
+
+
 def _dedup_rows(cfgs: Dict[str, np.ndarray], columns):
-    """→ (unique column dict [n_u], inverse index [n]) over ``columns``."""
-    key = np.stack([cfgs[k] for k in columns], axis=1)
-    uniq, inv = np.unique(key, axis=0, return_inverse=True)
-    return dict(zip(columns, uniq.T.copy())), inv.astype(np.int32)
+    """→ (unique column dict [n_u], inverse index [n]) over ``columns``:
+    ``np.unique(np.stack(cols, 1), axis=0, return_inverse=True)``'s answer,
+    unique rows in lexicographic order.  Each column is ranked with a 1-D
+    ``np.unique``; the ranks fold into one int64 key in mixed radix, the
+    first column most significant, so the key sorts rows lexicographically
+    and one 1-D ``np.unique`` of it dedups them.  A partial key whose radix
+    product would reach 2**62 is first compacted to dense ranks (counted
+    as ``dse.dedup.rekeys``)."""
+    key, span = np.zeros(len(cfgs[columns[0]]), np.int64), 1
+    for k in columns:
+        vals, code = np.unique(cfgs[k], return_inverse=True)
+        if span * len(vals) >= _KEY_SPAN_LIMIT:
+            uniq_key, key = np.unique(key, return_inverse=True)
+            span = len(uniq_key)
+            obs.count("dse.dedup.rekeys")
+        key = key * len(vals) + code
+        span *= len(vals)
+    _, first, inv = np.unique(key, return_index=True, return_inverse=True)
+    return ({k: cfgs[k][first] for k in columns},
+            inv.astype(np.int32))
 
 
 def _dedup_count_rows(cfgs: Dict[str, np.ndarray]):
