@@ -43,6 +43,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 #: Chunk of the mega-grid streams: a multiple of the grid's innermost
 #: (NoC) axis, so chunk-local dedup matches the global one.
 MEGA_CHUNK = 9800
+#: Top-k of the four-chip sweep (the chip benchmark's ``sweep`` cell: the
+#: co-design's pool size), whose sharded programs at ``MEGA_CHUNK`` a
+#: benchmark run of that cell leaves in the compile cache.
+SWEEP_TOPK = 6
 
 
 class SmokeFailure(AssertionError):
@@ -231,15 +235,14 @@ def phase_service(grid) -> dict:
     return health
 
 
-def phase_sharded(mega, ext, nets) -> None:
-    """Four chips: sharded against unsharded, bit for bit."""
+def phase_sharded_dense(ext, nets) -> None:
+    """Four chips: the dense evaluation sharded against unsharded, bit for
+    bit."""
     import jax
     import numpy as np
     from repro.core import energymodel
 
     devs = jax.devices()
-    # the dense check first, the quicker of the two: on four v5e chips
-    # the sharded mega stream alone took 474 s with its compiles
     t0 = time.perf_counter()
     e1, t1 = energymodel.evaluate_networks(ext, nets, shard=True)
     mesh = energymodel._cfg_mesh()
@@ -255,35 +258,56 @@ def phase_sharded(mega, ext, nets) -> None:
           f"last backend {energymodel.last_backend()!r}")
     log("[4] sharded evaluate_networks == unsharded, bit for bit")
 
-    seen = set()
-    dispatch = energymodel._dispatch_chunk
+
+def phase_sharded_stream(mega, nets) -> None:
+    """Four chips: the streamed per-layer sweep sharded against unsharded,
+    bit for bit, with every device computing chunks and no device
+    computing a chunk twice."""
+    import jax
+    import numpy as np
+    from repro.core import energymodel
+
+    devs = jax.devices()
+    # per round of the sharded stream, the devices whose shard of the
+    # grid kernel's output holds values (an idle device returns zeros)
+    computed = []
+    device_kernel = energymodel._jax_device_kernel
 
     def recording(*a, **k):
-        out = dispatch(*a, **k)
-        for x in out:
-            seen.update(x.devices())
-        return out
+        kern = device_kernel(*a, **k)
 
-    energymodel._dispatch_chunk = recording
+        def run(*args):
+            out = kern(*args)
+            computed.append(sorted(s.device.id
+                                   for s in out[0].addressable_shards
+                                   if bool(s.data.any())))
+            return out
+        return run
+
+    energymodel._jax_device_kernel = recording
     try:
         t0 = time.perf_counter()
         sh = energymodel.stream_layer_topk(mega, nets, chunk_size=MEGA_CHUNK,
-                                           shard=True, bound=0.05)
+                                           topk=SWEEP_TOPK, shard=True,
+                                           bound=0.05)
         t_sh = time.perf_counter() - t0
-        stream_devs = set(seen)
-        t0 = time.perf_counter()
-        un = energymodel.stream_layer_topk(mega, nets, chunk_size=MEGA_CHUNK,
-                                           shard=False, bound=0.05)
-        t_un = time.perf_counter() - t0
     finally:
-        energymodel._dispatch_chunk = dispatch
+        energymodel._jax_device_kernel = device_kernel
+    t0 = time.perf_counter()
+    un = energymodel.stream_layer_topk(mega, nets, chunk_size=MEGA_CHUNK,
+                                       topk=SWEEP_TOPK, shard=False,
+                                       bound=0.05)
+    t_un = time.perf_counter() - t0
+    n_chunks = -(-mega.n // MEGA_CHUNK)
     log(f"[4] stream_layer_topk {mega.n} points, chunk {MEGA_CHUNK}: "
         f"sharded {t_sh:.2f}s, unsharded {t_un:.2f}s incl. compile (smoke "
-        f"times, not metrics); chunks ran on devices "
-        f"{sorted(d.id for d in stream_devs)}")
-    check(stream_devs == set(devs),
-          f"sharded chunks ran on {sorted(d.id for d in stream_devs)}, "
-          f"not on all {len(devs)} devices")
+        f"times, not metrics); devices that computed, by round {computed}")
+    check({d for rnd in computed for d in rnd} == {d.id for d in devs},
+          f"sharded chunks computed on devices {computed} by round, not on "
+          f"all {len(devs)}")
+    check(sum(map(len, computed)) == n_chunks,
+          f"{sum(map(len, computed))} device computations by round "
+          f"{computed} for {n_chunks} chunks")
     for f in ("topk_idx", "topk_metric", "layer_energy", "layer_latency",
               "min_energy", "min_latency", "min_edp", "min_metric",
               "argmin", "layer_min_metric", "layer_argmin"):
@@ -311,8 +335,9 @@ def main(argv=None) -> int:
     nets = _all_networks()
     t0 = time.perf_counter()
     if args.chips == 4:
-        phase_sharded(accelerator.mega_grid(), accelerator.extended_grid(),
-                      nets)
+        # the dense check first, the quicker of the two
+        phase_sharded_dense(accelerator.extended_grid(), nets)
+        phase_sharded_stream(accelerator.mega_grid(), nets)
     else:
         phase_parity(accelerator.extended_grid(), nets)
         phase_codesign(accelerator.mega_grid(), nets, MEGA_CHUNK)

@@ -63,7 +63,9 @@ kernel does not lower there, and asking for it raises
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import warnings
 from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
@@ -355,11 +357,12 @@ def simulate_network(cfg: AcceleratorConfig, layers: Sequence[Layer],
 
 _LAYER_BUCKET = 256
 
-#: The engine's programs: the grid kernel in its four forms and the two
+#: The engine's programs: the grid kernel in its six forms and the three
 #: streamed folds (every other program counts under its own name too).
 _ENGINE_PROGRAMS = ("grid_kernel", "grid_kernel_layers",
                     "grid_kernel_sharded", "grid_kernel_sharded_layers",
-                    "stream_fold", "layer_fold")
+                    "grid_kernel_devices", "grid_kernel_devices_layers",
+                    "stream_fold", "layer_fold", "layer_fold_devices")
 
 
 def jit_cache_stats() -> Dict[str, int]:
@@ -828,6 +831,55 @@ def _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
     return _gather_combine_body(jnp, S, inv, coefs)
 
 
+_jitted_device_kernels: Dict[Tuple[str, bool, int], Any] = {}
+
+
+def _jax_device_kernel(backend: str = "jax", per_layer: bool = False):
+    """The grid kernel on every device of the mesh at once, each on its
+    own chunk and with no collective: one round of a chunked sweep.  The
+    devices' chunk inputs are stacked along the row axis
+    (:func:`_round_inputs`) and the outputs come back stacked the same
+    way.  A device whose ``active`` entry is False (no chunk in a last,
+    partial round) skips the kernel and returns zeros.  One program
+    serves every device; a single-device program committed to device
+    ``d`` would compile once per device."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    mesh = _cfg_mesh()
+    key = (backend, per_layer, mesh.devices.size)
+    if key not in _jitted_device_kernels:
+        rows, rep = P("cfg"), P()
+
+        def kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs, active):
+            def local(cfg_m_, cfg_u_, lay_, inv_m_, inv_, coefs_, active_):
+                def run():
+                    return _grid_kernel_body(
+                        jnp, segments, cfg_m_, cfg_u_, lay_, inv_m_, inv_,
+                        coefs_, backend=backend, per_layer=per_layer)
+
+                out = jax.eval_shape(run)
+                return jax.lax.cond(
+                    active_[0], run,
+                    lambda: tuple(jnp.zeros(o.shape, o.dtype) for o in out))
+
+            return jax.shard_map(
+                local, mesh=mesh,
+                in_specs=({k: rows for k in cfg_m}, {k: rows for k in cfg_u},
+                          {k: rep for k in lay}, rows, rows,
+                          {k: rows for k in coefs}, rows),
+                out_specs=(rows, rows),
+                check_vma=False)(cfg_m, cfg_u, lay, inv_m, inv, coefs,
+                                 active)
+
+        name = ("grid_kernel_devices_layers" if per_layer
+                else "grid_kernel_devices")
+        _jitted_device_kernels[key] = obs.Program(name, kernel,
+                                                  static_argnums=0)
+    return _jitted_device_kernels[key]
+
+
 def jax_available() -> bool:
     try:
         import jax                                     # noqa: F401
@@ -1080,22 +1132,95 @@ def _eval_fields(fields, lay, segments, backend: str, shard: bool,
         return obs.fetch("dse.eval.wait", *kern(segments, *args))
 
 
-def _dispatch_chunk(fc, lay, segments, device=None, backend: str = "jax",
-                    per_layer: bool = False):
-    """Async-dispatch one padded chunk on ``device`` (jax path): returns
-    uncollected device arrays so the host can prepare the next chunk — and
-    other devices can compute — while this one runs."""
-    import jax
+def _prepare_chunk(fc, backend: str = "jax"):
+    """One padded chunk's kernel inputs at the stream's buckets, counted
+    in ``dse.stream.rows_unique`` / ``dse.stream.rows_padded``."""
     with obs.span("dse.stream.prep"):
-        cfg_m, cfg_u, inv_m, inv, coefs = _prepare_fields(
-            fc, _UNIQUE_BUCKET, _MAPPING_BUCKET, backend=backend)
-    obs.count("dse.stream.rows_unique", int(inv.max()) + 1)
-    obs.count("dse.stream.rows_padded", inv_m.shape[0])
+        prep = _prepare_fields(fc, _UNIQUE_BUCKET, _MAPPING_BUCKET,
+                               backend=backend)
+    obs.count("dse.stream.rows_unique", int(prep[3].max()) + 1)
+    obs.count("dse.stream.rows_padded", prep[2].shape[0])
+    return prep
+
+
+def _dispatch_chunk(fc, lay, segments, backend: str = "jax",
+                    per_layer: bool = False):
+    """Async-dispatch one padded chunk (jax path): returns uncollected
+    device arrays so the host can prepare the next chunk while this one
+    runs."""
+    cfg_m, cfg_u, inv_m, inv, coefs = _prepare_chunk(fc, backend)
     with obs.span("dse.stream.dispatch"):
-        args = (cfg_m, cfg_u, lay, inv_m, inv, coefs)
-        if device is not None:
-            args = jax.device_put(args, device)
-        return _jax_grid_kernel(backend, per_layer)(segments, *args)
+        return _jax_grid_kernel(backend, per_layer)(
+            segments, cfg_m, cfg_u, lay, inv_m, inv, coefs)
+
+
+def _dispatch_chunks(chunks, lay, segments, backend: str, per_layer: bool,
+                     shard: bool):
+    """Async-dispatch every chunk of ``chunks`` (``(ci, start, stop, fc)``)
+    and yield ``(ci, start, stop, fc, e, t)`` with ``e``/``t`` on the
+    device that computes them.  With ``shard`` and several devices the
+    chunks go out in rounds, one per device, each round one launch of
+    :func:`_jax_device_kernel` for all devices."""
+    if not (shard and host_device_count() > 1):
+        for ci, start, stop, fc in chunks:
+            yield (ci, start, stop, fc,
+                   *_dispatch_chunk(fc, lay, segments, backend, per_layer))
+        return
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = _cfg_mesh()
+    lay_d = jax.device_put(lay, NamedSharding(mesh, P()))
+    kern = _jax_device_kernel(backend, per_layer)
+    for rnd in _rounds(chunks, mesh.devices.size):
+        args = _round_inputs([_prepare_chunk(fc, backend) for *_, fc in rnd],
+                             mesh)
+        with obs.span("dse.stream.dispatch"):
+            e_g, t_g = kern(segments, args[0], args[1], lay_d, *args[2:])
+        e_on = {s.device: s.data for s in e_g.addressable_shards}
+        t_on = {s.device: s.data for s in t_g.addressable_shards}
+        for chunk, dev in zip(rnd, mesh.devices.flat):
+            yield (*chunk, e_on[dev], t_on[dev])
+
+
+def _rounds(items, n: int):
+    """``items`` in lists of ``n``, the last one possibly shorter."""
+    it = iter(items)
+    while rnd := list(itertools.islice(it, n)):
+        yield rnd
+
+
+def _round_inputs(preps, mesh):
+    """One round's kernel inputs, one prepared chunk per device, and the
+    [n_dev] ``active`` flags: each device's put on it under
+    ``dse.stream.dispatch.device``, then the global arrays, the chunks
+    stacked along the row axis, for :func:`_jax_device_kernel`.  A device
+    past the last chunk gets the first chunk's inputs for their shapes
+    and ``active`` False, so it computes nothing.  The unique-row axes
+    are padded to the round's largest, as :func:`_prepare_fields` pads
+    them to a bucket."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    n_u = max(p[2].shape[0] for p in preps)
+    n_m = max(next(iter(p[0].values())).shape[0] for p in preps)
+    parts = []
+    for d, dev in enumerate(mesh.devices.flat):
+        cfg_m, cfg_u, inv_m, inv, coefs = preps[d if d < len(preps) else 0]
+        if inv_m.shape[0] < n_u:
+            cfg_u = {k: _pad_rows(v, n_u) for k, v in cfg_u.items()}
+            inv_m = np.concatenate(
+                [inv_m, np.zeros(n_u - inv_m.shape[0], inv_m.dtype)])
+        cfg_m = {k: _pad_rows(v, n_m) for k, v in cfg_m.items()}
+        with (obs.span("dse.stream.dispatch.device") if d < len(preps)
+              else contextlib.nullcontext()):
+            parts.append(jax.device_put(
+                (cfg_m, cfg_u, inv_m, inv, coefs,
+                 np.array([d < len(preps)])), dev))
+    rows = NamedSharding(mesh, P("cfg"))
+    return jax.tree.map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (len(xs) * xs[0].shape[0],) + xs[0].shape[1:], rows, list(xs)),
+        *parts)
 
 
 def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
@@ -1105,10 +1230,12 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
     ([n, n_net, n_layer] in per-layer mode).
 
     With ``shard=True`` and several host devices, whole chunks round-robin
-    across the devices: each device runs the complete two-level-dedup
-    kernel on its chunks (no duplicated mapping work, no collectives), and
-    asynchronous dispatch keeps every device busy while the host dedups
-    the next chunk.  In-flight chunks are bounded to 2 per device."""
+    across the devices in rounds, one chunk per device: each device runs
+    the complete two-level-dedup kernel on its chunk (no duplicated
+    mapping work, no collectives) under one program for all of them
+    (:func:`_jax_device_kernel`), and asynchronous dispatch keeps the
+    devices busy while the host dedups the next round.  In-flight chunks
+    are bounded to 2 per device."""
     shape = ((n, n_net, _layer_axis_len(segments)) if per_layer
              else (n, n_net))
     e = np.empty(shape)
@@ -1130,9 +1257,7 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
             t[start:stop] = tc[:stop - start]
         return e, t
 
-    import jax
-    devs = jax.devices()
-    n_dev = len(devs) if shard else 1
+    n_dev = host_device_count() if shard else 1
     pending: list = []
 
     def drain(item):
@@ -1141,10 +1266,8 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
         t[start:stop] = np.asarray(tc)[:stop - start]
 
     with x64():
-        for ci, start, stop, fc in chunks():
-            dev = devs[ci % n_dev] if n_dev > 1 else None
-            ec, tc = _dispatch_chunk(fc, lay, segments, dev, backend,
-                                     per_layer)
+        for _, start, stop, _, ec, tc in _dispatch_chunks(
+                chunks(), lay, segments, backend, per_layer, shard):
             pending.append((start, stop, ec, tc))
             if len(pending) > 2 * n_dev:
                 drain(pending.pop(0))
@@ -1640,8 +1763,9 @@ def stream_networks(grid: ConfigGrid,
             collect(mask, e, t, start)
             emit(ci)
     else:
-        # Round-robin the chunk kernels across devices (async dispatch);
-        # the cheap stateful reduction runs in chunk order on device 0.
+        # Chunk kernels go out in rounds over the devices (async
+        # dispatch); the cheap stateful reduction runs in chunk order on
+        # device 0.
         import jax
         devs = jax.devices()
         pending: list = []
@@ -1683,9 +1807,8 @@ def stream_networks(grid: ConfigGrid,
                                  t_h[pos[m], j]))
                 emit(ci)
 
-            for ci, start, stop, fc in chunks():
-                dev = devs[ci % n_dev] if n_dev > 1 else None
-                e_d, t_d = _dispatch_chunk(fc, lay, segments, dev, backend)
+            for ci, start, stop, fc, e_d, t_d in _dispatch_chunks(
+                    chunks(), lay, segments, backend, False, n_dev > 1):
                 pending.append((ci, start, stop, e_d, t_d,
                                 fc if verify is not None else None))
                 if len(pending) > 2 * n_dev:
@@ -1851,6 +1974,215 @@ def _jax_layer_reduce_step():
     return _jitted_layer_reduce
 
 
+_jitted_layer_fold_devices: Dict[int, Any] = {}
+
+
+def _jax_layer_fold_devices():
+    """The per-layer fold on every device of the mesh at once: device
+    ``d`` folds its chunk of a round (:func:`_jax_device_kernel`) into its
+    own state, held at index ``d`` of every state leaf's leading axis, in
+    the body of :func:`_jax_layer_reduce_step`.  ``base`` and ``m_valid``
+    are [n_dev]; a device with no chunk in the round gets ``m_valid`` 0:
+    it skips the fold, keeps its state and returns an empty mask."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    mesh = _cfg_mesh()
+    n_dev = mesh.devices.size
+    if n_dev not in _jitted_layer_fold_devices:
+        rows, rep = P("cfg"), P()
+
+        def red(metric, topk, e, t, state, base, m_valid, bound,
+                lay_valid):
+            def local(e_, t_, state_, base_, m_valid_, bound_, lay_valid_):
+                state0 = tuple(s[0] for s in state_)
+
+                def run():
+                    return _layer_reduce_body(
+                        jnp, metric, topk, e_, t_, base_[0], m_valid_[0],
+                        bound_, lay_valid_, state0)
+
+                def skip():
+                    es = jnp.full(e_.shape[:2], np.inf, e_.dtype)
+                    return state0, jnp.zeros(es.shape, bool), es, es
+
+                new, mask, es, ts = jax.lax.cond(m_valid_[0] > 0, run, skip)
+                return tuple(s[None] for s in new), mask, es, ts
+
+            leaves = tuple(rows for _ in state)
+            return jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(rows, rows, leaves, rows, rows, rep, rep),
+                out_specs=(leaves, rows, rows, rows),
+                check_vma=False)(e, t, state, base, m_valid, bound,
+                                 lay_valid)
+
+        _jitted_layer_fold_devices[n_dev] = obs.Program(
+            "layer_fold_devices", red, static_argnums=(0, 1))
+    return _jitted_layer_fold_devices[n_dev]
+
+
+def _layer_fold_init(k: int, n_net: int, n_layer: int) -> tuple:
+    """The per-layer fold's state before any chunk."""
+    return (np.full((k, n_net), np.inf),              # top_v
+            np.full((k, n_net), -1, np.int64),        # top_i
+            np.zeros((k, n_net, n_layer)),            # top_e
+            np.zeros((k, n_net, n_layer)),            # top_t
+            np.full(n_net, np.inf),                   # min_energy
+            np.full(n_net, np.inf),                   # min_latency
+            np.full(n_net, np.inf),                   # min_edp
+            np.full(n_net, np.inf),                   # min_metric
+            np.full(n_net, -1, np.int64),             # argmin
+            np.full((n_net, n_layer), np.inf),        # layer_min_metric
+            np.full((n_net, n_layer), -1, np.int64))  # layer_argmin
+
+
+def _merge_layer_states(states: Sequence[tuple]) -> tuple:
+    """Fold states over disjoint sets of grid rows (host arrays, flat
+    indices global) → the state one fold over all of them ends in, bit for
+    bit: the top-k by (metric, flat index), and each minimum with the
+    lower flat index on a tie, as the fold keeps the earlier row.  Pure
+    selection: no value is recomputed."""
+    (top_v, top_i, top_e, top_t, min_e, min_t, min_edp, min_m, argm,
+     lmin, larg) = states[0]
+    k = top_v.shape[0]
+    for (v2, i2, e2, t2, e_min2, t_min2, edp2, m2, arg2, lm2,
+         la2) in states[1:]:
+        all_v = np.concatenate([top_v, v2], axis=0)
+        all_i = np.concatenate([top_i, i2], axis=0)
+        order = np.lexsort((all_i, all_v), axis=0)[:k]
+        top_v = np.take_along_axis(all_v, order, axis=0)
+        top_i = np.take_along_axis(all_i, order, axis=0)
+        top_e = np.take_along_axis(np.concatenate([top_e, e2], axis=0),
+                                   order[:, :, None], axis=0)
+        top_t = np.take_along_axis(np.concatenate([top_t, t2], axis=0),
+                                   order[:, :, None], axis=0)
+        min_e = np.minimum(min_e, e_min2)
+        min_t = np.minimum(min_t, t_min2)
+        min_edp = np.minimum(min_edp, edp2)
+        better = (m2 < min_m) | ((m2 == min_m) & (arg2 < argm))
+        min_m = np.where(better, m2, min_m)
+        argm = np.where(better, arg2, argm)
+        lbetter = (lm2 < lmin) | ((lm2 == lmin) & (la2 < larg))
+        lmin = np.where(lbetter, lm2, lmin)
+        larg = np.where(lbetter, la2, larg)
+    return (top_v, top_i, top_e, top_t, min_e, min_t, min_edp, min_m, argm,
+            lmin, larg)
+
+
+def _layer_topk_rounds(chunks, state, lay, segments, backend, metric, k,
+                       bound, lay_valid, chunk, names, nan_guard, verify,
+                       collect, emit):
+    """The jax path of :func:`stream_layer_topk` over every device of the
+    mesh.  Chunks go out in rounds, one chunk per device, and each round
+    is one launch of the grid kernel and one of the fold for all devices
+    (:func:`_jax_device_kernel`, :func:`_jax_layer_fold_devices`).  Each
+    device folds its chunks into its own state, which stays on it; the
+    states merge on the host once, at the end (``dse.stream.merge``,
+    :func:`_merge_layer_states`), into the one-device fold's state bit for
+    bit.  ``state`` (initial or resumed) starts on device 0.  Guard,
+    verifier and boundary collection run per chunk as on one device; the
+    round commits when all of its chunks pass.  ``emit(ci, state)``, when
+    given, gets the merged state after each round.  Returns the merged
+    state as host arrays."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = _cfg_mesh()
+    n_dev = mesh.devices.size
+    rows = NamedSharding(mesh, P("cfg"))
+    kern = _jax_device_kernel(backend, per_layer=True)
+    fold = _jax_layer_fold_devices()
+    b = 0.0 if bound is None else float(bound)
+    init = _layer_fold_init(k, *lay_valid.shape)
+    keep = verify is not None
+    per_dev = np.zeros(n_dev, np.int64)
+    pending: list = []
+
+    def merged(dstate):
+        host = obs.fetch("dse.stream.wait", *dstate)
+        with obs.span("dse.stream.merge"):
+            return _merge_layer_states(
+                [tuple(s[d] for s in host) for d in range(n_dev)])
+
+    def finish(item):
+        nonlocal committed
+        rnd, prev, new, mask, es, ts, e_g, t_g = item
+        if nan_guard or bound is not None or keep:   # the host reads them
+            es, ts, mask = obs.fetch("dse.stream.wait", es, ts, mask)
+        with obs.span("dse.stream.collect"):
+            for d, (ci, start, stop, fc) in enumerate(rnd):
+                r = slice(d * chunk, (d + 1) * chunk)
+                if nan_guard:       # raises BEFORE the round commits
+                    _guard_chunk(ci, start, stop, es[r], ts[r], names)
+                if keep:
+                    verify.check_chunk(ci, start, stop, fc,
+                                       np.asarray(e_g)[r],
+                                       np.asarray(t_g)[r])
+                    verify.check_fold(
+                        ci, start, stop,
+                        tuple(np.asarray(s)[d] for s in prev),
+                        tuple(np.asarray(s)[d] for s in new),
+                        es=es[r], ts=ts[r], mask=mask[r])
+            committed = new
+            if bound is not None:
+                for d, (ci, start, stop, fc) in enumerate(rnd):
+                    r = slice(d * chunk, (d + 1) * chunk)
+                    collect(mask[r], es[r], ts[r], start)
+        if emit is not None:
+            emit(rnd[-1][0], merged(committed))
+
+    with x64():
+        lay_d = jax.device_put(lay, NamedSharding(mesh, P()))
+        dstate = committed = jax.device_put(
+            tuple(np.stack([s] + [i] * (n_dev - 1))
+                  for s, i in zip(state, init)), rows)
+        for rnd in _rounds(chunks, n_dev):
+            args = _round_inputs(
+                [_prepare_chunk(fc, backend) for *_, fc in rnd], mesh)
+            pad = [0] * (n_dev - len(rnd))
+            base = np.array([s for _, s, _, _ in rnd] + pad, np.int64)
+            valid = np.array([p - s for _, s, p, _ in rnd] + pad, np.int64)
+            if _CHUNK_HOOK is not None:     # faults fire with every earlier
+                for item in pending:        # round committed, as on one
+                    finish(item)            # device
+                pending.clear()
+            with obs.span("dse.stream.dispatch"):
+                e_g, t_g = kern(segments, args[0], args[1], lay_d,
+                                *args[2:])
+                if _CHUNK_HOOK is not None:
+                    e_g, t_g = _hook_round(rnd, e_g, t_g, chunk, rows)
+                prev = dstate
+                dstate, mask, es, ts = fold(
+                    metric, k, e_g, t_g, dstate, jax.device_put(base, rows),
+                    jax.device_put(valid, rows), b, lay_valid)
+            per_dev[:len(rnd)] += 1
+            pending.append(
+                ([(ci, s, p, fc if keep else None) for ci, s, p, fc in rnd],
+                 prev if keep else None, dstate, mask, es, ts,
+                 e_g if keep else None, t_g if keep else None))
+            if len(pending) > 2:
+                finish(pending.pop(0))
+        for item in pending:
+            finish(item)
+        state = merged(committed)
+    for n_chunks in per_dev:
+        obs.count("dse.stream.chunks_per_device", int(n_chunks))
+    return state
+
+
+def _hook_round(rnd, e_g, t_g, chunk, rows):
+    """The chunk hook (fault injection) on each chunk of a round."""
+    import jax
+    e_h, t_h = np.array(e_g), np.array(t_g)
+    for d, (ci, *_) in enumerate(rnd):
+        r = slice(d * chunk, (d + 1) * chunk)
+        e_c, t_c = _apply_chunk_hook(ci, e_h[r], t_h[r])
+        e_h[r], t_h[r] = np.asarray(e_c), np.asarray(t_c)
+    return jax.device_put(e_h, rows), jax.device_put(t_h, rows)
+
+
 @obs.traced("dse.stream")
 def stream_layer_topk(grid: ConfigGrid,
                       networks: Mapping[str, Sequence[Layer]],
@@ -1908,17 +2240,7 @@ def stream_layer_topk(grid: ConfigGrid,
         lay_valid = np.arange(n_layer)[None, :] < lay_counts[:, None]
 
         k = int(topk)
-        state = (np.full((k, n_net), np.inf),              # top_v
-                 np.full((k, n_net), -1, np.int64),        # top_i
-                 np.zeros((k, n_net, n_layer)),            # top_e
-                 np.zeros((k, n_net, n_layer)),            # top_t
-                 np.full(n_net, np.inf),                   # min_energy
-                 np.full(n_net, np.inf),                   # min_latency
-                 np.full(n_net, np.inf),                   # min_edp
-                 np.full(n_net, np.inf),                   # min_metric
-                 np.full(n_net, -1, np.int64),             # argmin
-                 np.full((n_net, n_layer), np.inf),        # layer_min_metric
-                 np.full((n_net, n_layer), -1, np.int64))  # layer_argmin
+        state = _layer_fold_init(k, n_net, n_layer)
         b = 0.0 if bound is None else float(bound)
         cand: Dict[str, list] = {nm: [] for nm in names}
         n_chunks = -(-n // chunk)
@@ -1938,7 +2260,7 @@ def stream_layer_topk(grid: ConfigGrid,
         if resume_from is not None:
             verify.check_resume(state, cand)
 
-    def emit(ci):
+    def emit(ci, state):
         if on_chunk is None:
             return
         on_chunk(StreamFoldState(
@@ -1989,20 +2311,19 @@ def stream_layer_topk(grid: ConfigGrid,
                                   es=es, ts=ts, mask=mask)
             state = new_state
             collect(mask, es, ts, start)
-            emit(ci)
+            emit(ci, state)
+    elif shard and host_device_count() > 1:
+        state = _layer_topk_rounds(
+            chunks(), state, lay, segments, backend, metric, k, bound,
+            lay_valid, chunk, names, nan_guard, verify, collect,
+            emit if on_chunk is not None else None)
     else:
-        import jax
-        devs = jax.devices()
-        n_dev = host_device_count() if shard else 1
         pending: list = []
         with x64():
             def reduce_one(item):
                 nonlocal state
                 ci, start, stop, e_d, t_d, fc = item
                 with obs.span("dse.stream.dispatch"):
-                    if n_dev > 1:
-                        e_d = jax.device_put(e_d, devs[0])
-                        t_d = jax.device_put(t_d, devs[0])
                     e_d, t_d = _apply_chunk_hook(ci, e_d, t_d)
                     new_state, mask, es, ts = _jax_layer_reduce_step()(
                         metric, k, e_d, t_d, state, np.int64(start),
@@ -2023,15 +2344,14 @@ def stream_layer_topk(grid: ConfigGrid,
                                           mask=np.asarray(mask))
                     state = new_state
                     collect(mask, es, ts, start)
-                    emit(ci)
+                    emit(ci, state)
 
             for ci, start, stop, fc in chunks():
-                dev = devs[ci % n_dev] if n_dev > 1 else None
-                ec, tc = _dispatch_chunk(fc, lay, segments, dev, backend,
+                ec, tc = _dispatch_chunk(fc, lay, segments, backend,
                                          per_layer=True)
                 pending.append((ci, start, stop, ec, tc,
                                 fc if verify is not None else None))
-                if len(pending) > 2 * n_dev:
+                if len(pending) > 2:
                     reduce_one(pending.pop(0))
             for item in pending:
                 reduce_one(item)
@@ -2109,28 +2429,17 @@ def merge_layer_topk(a: LayerTopK, b: LayerTopK) -> LayerTopK:
             f"cannot merge streams with different top-k sizes "
             f"{a.topk_idx.shape[0]} vs {b.topk_idx.shape[0]}")
     off = int(a.n_cfg)
-    k = a.topk_idx.shape[0]
-
-    # -- top-k with the per-layer rows gathered alongside ------------------
-    all_v = np.concatenate([a.topk_metric, b.topk_metric], axis=0)
-    all_i = np.concatenate([a.topk_idx, _shift_idx(b.topk_idx, off)],
-                           axis=0)
-    order = np.lexsort((all_i, all_v), axis=0)[:k]
-    top_v = np.take_along_axis(all_v, order, axis=0)
-    top_i = np.take_along_axis(all_i, order, axis=0)
-    all_e = np.concatenate([a.layer_energy, b.layer_energy], axis=0)
-    all_t = np.concatenate([a.layer_latency, b.layer_latency], axis=0)
-    top_e = np.take_along_axis(all_e, order[:, :, None], axis=0)
-    top_t = np.take_along_axis(all_t, order[:, :, None], axis=0)
-
-    # -- aggregate minima: strict < keeps the LOWER-index (a) side on ties
-    better = b.min_metric < a.min_metric
-    min_m = np.where(better, b.min_metric, a.min_metric)
-    argm = np.where(better, _shift_idx(b.argmin, off), a.argmin)
-    lbetter = b.layer_min_metric < a.layer_min_metric
-    lmin = np.where(lbetter, b.layer_min_metric, a.layer_min_metric)
-    larg = np.where(lbetter, _shift_idx(b.layer_argmin, off),
-                    a.layer_argmin)
+    # b's rows follow a's, so the lower index on a tie is a's, as a fold
+    # over the concatenated grid keeps the earlier row
+    (top_v, top_i, top_e, top_t, min_e, min_t, min_edp, min_m, argm,
+     lmin, larg) = _merge_layer_states([
+        (a.topk_metric, a.topk_idx, a.layer_energy, a.layer_latency,
+         a.min_energy, a.min_latency, a.min_edp, a.min_metric, a.argmin,
+         a.layer_min_metric, a.layer_argmin),
+        (b.topk_metric, _shift_idx(b.topk_idx, off), b.layer_energy,
+         b.layer_latency, b.min_energy, b.min_latency, b.min_edp,
+         b.min_metric, _shift_idx(b.argmin, off), b.layer_min_metric,
+         _shift_idx(b.layer_argmin, off))])
 
     b_idx = b_e = b_t = None
     if a.bound is not None:
@@ -2154,9 +2463,7 @@ def merge_layer_topk(a: LayerTopK, b: LayerTopK) -> LayerTopK:
         layer_counts=a.layer_counts,
         topk_idx=top_i, topk_metric=top_v,
         layer_energy=top_e, layer_latency=top_t,
-        min_energy=np.minimum(a.min_energy, b.min_energy),
-        min_latency=np.minimum(a.min_latency, b.min_latency),
-        min_edp=np.minimum(a.min_edp, b.min_edp),
+        min_energy=min_e, min_latency=min_t, min_edp=min_edp,
         min_metric=min_m, argmin=argm,
         layer_min_metric=lmin, layer_argmin=larg,
         bound=a.bound, boundary_idx=b_idx,

@@ -100,6 +100,45 @@ def test_layer_reduce_step_compiles_for_v5e(one_chip, networks):
              static=("edp", k))
 
 
+def test_device_round_programs_compile_for_v5e(one_chip, topo, networks,
+                                               monkeypatch):
+    """The sharded stream's two programs, the per-layer grid kernel and
+    the fold on all four chips of the v5e 2x2 at once, one chunk of
+    ``N_ROWS`` points each: they compile, and with no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices), ("cfg",))
+    monkeypatch.setattr(energymodel, "_cfg_mesh", lambda: mesh)
+    monkeypatch.setattr(energymodel, "_jitted_device_kernels", {})
+    monkeypatch.setattr(energymodel, "_jitted_layer_fold_devices", {})
+    rows, rep = NamedSharding(mesh, P("cfg")), NamedSharding(mesh, P())
+    n_dev = mesh.devices.size
+
+    def stacked(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            (n_dev * np.shape(x)[0],) + np.shape(x)[1:], np.asarray(x).dtype,
+            sharding=rows), tree)
+
+    segments, (cfg_m, cfg_u, lay, inv_m, inv, coefs) = _kernel_args(
+        networks, True)
+    kernel = _compile(energymodel._jax_device_kernel("jax", True),
+                      stacked(cfg_m), stacked(cfg_u), _shapes(lay, rep),
+                      stacked(inv_m), stacked(inv), stacked(coefs),
+                      stacked(np.ones(1, bool)), static=(segments,))
+    n_net, n_layer, k = len(networks), energymodel._layer_axis_len(segments), 8
+    e = np.zeros((N_ROWS, n_net, n_layer))
+    fold = _compile(
+        energymodel._jax_layer_fold_devices(), stacked(e), stacked(e),
+        stacked(tuple(s[None] for s in energymodel._layer_fold_init(
+            k, n_net, n_layer))),
+        stacked(np.zeros(1, np.int64)), stacked(np.zeros(1, np.int64)),
+        *_shapes((np.zeros(()), np.zeros((n_net, n_layer), bool)), rep),
+        static=("edp", k))
+    for compiled in (kernel, fold):
+        hlo = compiled.as_text()
+        assert not any(op in hlo for op in ("all-gather", "all-reduce",
+                                            "collective-permute"))
+
+
 def test_batch_schedule_hetero_solve_compiles_for_v5e(one_chip):
     """Both jitted stages at the co-design's shapes: 111 chips × 18
     networks = 1,998 problems of up to 3 core types over the 256-layer
