@@ -2318,23 +2318,27 @@ def stream_layer_topk(grid: ConfigGrid,
             lay_valid, chunk, names, nan_guard, verify, collect,
             emit if on_chunk is not None else None)
     else:
+        # Each chunk's kernel and fold are enqueued together, and chunk i
+        # is fetched only once chunk i+1's are queued behind it, so the
+        # device keeps working while the host collects and preps.  Chunks
+        # commit in order: ``state`` is the committed fold state, and a
+        # chunk that fails its guard or verifier raises before it commits
+        # (the fold enqueued after it is dropped).
+        keep = verify is not None
         pending: list = []
         with x64():
-            def reduce_one(item):
+            def finish(item, ahead):
                 nonlocal state
-                ci, start, stop, e_d, t_d, fc = item
-                with obs.span("dse.stream.dispatch"):
-                    e_d, t_d = _apply_chunk_hook(ci, e_d, t_d)
-                    new_state, mask, es, ts = _jax_layer_reduce_step()(
-                        metric, k, e_d, t_d, state, np.int64(start),
-                        np.int64(stop - start), float(b), lay_valid)
+                ci, start, stop, fc, new_state, mask, es, ts, e_d, t_d = item
                 if nan_guard or bound is not None:   # the host reads them
                     es, ts, mask = obs.fetch("dse.stream.wait", es, ts,
                                              mask)
+                    if ahead:
+                        obs.count("dse.stream.fetch_ahead", 1)
                 with obs.span("dse.stream.collect"):
                     if nan_guard:     # raises BEFORE the fold commits
                         _guard_chunk(ci, start, stop, es, ts, names)
-                    if verify is not None:
+                    if keep:
                         verify.check_chunk(ci, start, stop, fc,
                                            np.asarray(e_d),
                                            np.asarray(t_d))
@@ -2346,15 +2350,28 @@ def stream_layer_topk(grid: ConfigGrid,
                     collect(mask, es, ts, start)
                     emit(ci, state)
 
+            dstate = state
             for ci, start, stop, fc in chunks():
-                ec, tc = _dispatch_chunk(fc, lay, segments, backend,
-                                         per_layer=True)
-                pending.append((ci, start, stop, ec, tc,
-                                fc if verify is not None else None))
-                if len(pending) > 2:
-                    reduce_one(pending.pop(0))
+                e_d, t_d = _dispatch_chunk(fc, lay, segments, backend,
+                                           per_layer=True)
+                if _CHUNK_HOOK is not None:     # faults fire with every
+                    for item in pending:        # earlier chunk committed
+                        finish(item, False)
+                    pending.clear()
+                with obs.span("dse.stream.dispatch"):
+                    e_d, t_d = _apply_chunk_hook(ci, e_d, t_d)
+                    dstate, mask, es, ts = _jax_layer_reduce_step()(
+                        metric, k, e_d, t_d, dstate, np.int64(start),
+                        np.int64(stop - start), float(b), lay_valid)
+                pending.append((ci, start, stop, fc if keep else None,
+                                dstate, mask, es, ts,
+                                e_d if keep else None,
+                                t_d if keep else None))
+                del e_d, t_d
+                if len(pending) > 1:
+                    finish(pending.pop(0), True)
             for item in pending:
-                reduce_one(item)
+                finish(item, False)
         state = obs.fetch("dse.stream.wait", *state)
 
     (top_v, top_i, top_e, top_t, min_e, min_t, min_edp, min_m, argm,
