@@ -136,40 +136,6 @@ def _rss_now_mb() -> float:
     return float("nan")                                # pragma: no cover
 
 
-def _pallas_columns(grid, nets, e_j, t_j, chunk: int | None = None) -> dict:
-    """Timing + parity of the fused Pallas count-terms backend against the
-    jax engine output ``(e_j, t_j)`` on the same grid.  Returns the v3
-    ``pallas_*`` level columns (None-valued when Pallas is unavailable —
-    the schema keeps the keys so consumers never branch on presence)."""
-    if not energymodel.pallas_available():              # pragma: no cover
-        return dict(backend_pallas=False, pallas_warm_s=None,
-                    max_rel_err_pallas_energy=None,
-                    max_rel_err_pallas_latency=None)
-    kw = dict(backend="pallas")
-    if chunk is not None:
-        kw["chunk_size"] = chunk
-    # the parity pass doubles as the untimed pre-warm (traces + dispatch
-    # caches populated), so the timed reps measure the steady state
-    e_p, t_p = energymodel.evaluate_networks(grid, nets, **kw)
-    warm_s = min(
-        _timed(lambda: energymodel.evaluate_networks(grid, nets,
-                                                     **kw))[1] / 1e6
-        for _ in range(2))
-    return dict(
-        backend_pallas=True, pallas_warm_s=round(warm_s, 4),
-        max_rel_err_pallas_energy=float(np.max(np.abs(e_p - e_j) / e_j)),
-        max_rel_err_pallas_latency=float(np.max(np.abs(t_p - t_j) / t_j)))
-
-
-def _pallas_txt(level: dict) -> str:
-    """Human-readable pallas clause for the CSV derived column."""
-    if level.get("pallas_warm_s") is None:
-        return "pallas n/a"
-    perr = max(level["max_rel_err_pallas_energy"],
-               level["max_rel_err_pallas_latency"])
-    return f"pallas {level['pallas_warm_s']:.2f}s (err<={perr:.1e})"
-
-
 def bench_dse_scale(quick: bool = False) -> list:
     nets = {n: topology.get_network(n) for n in topology.NETWORKS}
     use_jax = dse._use_jax_default()
@@ -214,11 +180,10 @@ def bench_dse_scale(quick: bool = False) -> list:
             jit_precached=True, jit_warm_s=round(warm_s, 4),
             speedup_warm=round(numpy_s / warm_s, 2),
             max_rel_err_energy=err_e, max_rel_err_latency=err_t)
-        level.update(_pallas_columns(grid, nets, e_j, t_j))
         results.append(level)
         _emit(f"dse_scale_{name}", numpy_s * 1e6,
               f"{grid.n} pts: numpy {numpy_s:.2f}s vs jit {warm_s:.2f}s "
-              f"warm → {numpy_s / warm_s:.1f}x, {_pallas_txt(level)}, "
+              f"warm → {numpy_s / warm_s:.1f}x, "
               f"err<={max(err_e, err_t):.1e}")
 
     results.append(_bench_mega_level(nets, use_jax, quick))
@@ -278,11 +243,9 @@ def _bench_mega_level(nets, use_jax: bool, quick: bool) -> dict:
         subsample_stride=97,
         rss_now_mb=round(_rss_now_mb(), 1),
         rss_peak_process_mb=round(_rss_peak_mb(), 1))
-    level.update(_pallas_columns(grid, nets, e_c, t_c, chunk=chunk))
     _emit(f"dse_scale_{name}", warm_s * 1e6,
           f"{grid.n} pts chunked({chunk}): {warm_s:.2f}s, sharded "
           f"{sharded_s:.2f}s ({n_dev} dev), stream {stream_s:.2f}s, "
-          f"{_pallas_txt(level)}, "
           f"err<={max(err_e, err_t):.1e}, "
           f"rss {level['rss_peak_process_mb']:.0f}MB peak")
     return level
@@ -363,8 +326,8 @@ def bench_partition_batch(nets) -> dict:
 
 
 def _per_layer_parity(grid, nets) -> dict:
-    """`per_layer=True` parity across jax / pallas / chunked / sharded
-    against the numpy per-layer reference (all ≤1e-6 guardrailed)."""
+    """`per_layer=True` parity across jax / chunked / sharded against the
+    numpy per-layer reference (all ≤1e-6 guardrailed)."""
     def err(a, b):
         d = np.abs(a - b)
         with np.errstate(invalid="ignore", divide="ignore"):
@@ -379,19 +342,10 @@ def _per_layer_parity(grid, nets) -> dict:
                                              per_layer=True, chunk_size=64)
     e_s, t_s = energymodel.evaluate_networks(grid, nets, backend="jax",
                                              per_layer=True, shard=True)
-    out = dict(
+    return dict(
         max_rel_err_per_layer_jax=max(err(e_j, e_n), err(t_j, t_n)),
         max_rel_err_per_layer_chunked=max(err(e_c, e_j), err(t_c, t_j)),
         max_rel_err_per_layer_sharded=max(err(e_s, e_j), err(t_s, t_j)))
-    if energymodel.pallas_available():
-        e_p, t_p = energymodel.evaluate_networks(grid, nets,
-                                                 backend="pallas",
-                                                 per_layer=True)
-        out["max_rel_err_per_layer_pallas"] = max(err(e_p, e_j),
-                                                  err(t_p, t_j))
-    else:                                              # pragma: no cover
-        out["max_rel_err_per_layer_pallas"] = None
-    return out
 
 
 def bench_codesign(nets, quick: bool) -> dict:
@@ -805,19 +759,12 @@ def _check_bench_payload(payload: dict, quick: bool = False) -> list:
     if payload.get("schema") != "bench_dse/v6":
         problems.append(f"unexpected schema {payload.get('schema')!r}")
     for lv in payload.get("levels", []):
-        for key in ("max_rel_err_energy", "max_rel_err_latency",
-                    "max_rel_err_pallas_energy",
-                    "max_rel_err_pallas_latency"):
+        for key in ("max_rel_err_energy", "max_rel_err_latency"):
             if key not in lv:
                 problems.append(f"level {lv.get('name')}: missing {key!r}")
             elif lv[key] is not None and lv[key] > 1e-6:
                 problems.append(
                     f"level {lv.get('name')}: {key}={lv.get(key):.2e}")
-        if (payload.get("backends", {}).get("pallas")
-                and lv.get("pallas_warm_s") is None):
-            problems.append(
-                f"level {lv.get('name')}: pallas available but no "
-                "pallas_warm_s timing recorded")
         if lv.get("chunked") and not lv.get("stream_consistent", True):
             problems.append(
                 f"level {lv.get('name')}: stream reductions diverged")
@@ -839,8 +786,7 @@ def _check_bench_payload(payload: dict, quick: bool = False) -> list:
                 f"{floor}x floor")
         for key in ("max_rel_err_per_layer_jax",
                     "max_rel_err_per_layer_chunked",
-                    "max_rel_err_per_layer_sharded",
-                    "max_rel_err_per_layer_pallas"):
+                    "max_rel_err_per_layer_sharded"):
             if key not in cod:
                 problems.append(f"codesign: missing {key!r}")
             elif cod[key] is not None and cod[key] > 1e-6:
@@ -930,8 +876,7 @@ def write_bench_json(levels: list, part: dict, codesign: dict,
         schema="bench_dse/v6",
         cpu_count=os.cpu_count(),
         n_devices=energymodel.host_device_count(),
-        backends=dict(jax=use_jax,
-                      pallas=energymodel.pallas_available()),
+        backends=dict(jax=use_jax),
         persistent_cache=cache_info,
         jit_cache=energymodel.jit_cache_stats(),
         levels=levels,

@@ -247,8 +247,8 @@ def phase_sharded_dense(ext, nets) -> None:
     e1, t1 = energymodel.evaluate_networks(ext, nets, shard=True)
     mesh = energymodel._cfg_mesh()
     e0, t0_ = energymodel.evaluate_networks(ext, nets, shard=False)
-    log(f"[4] evaluate_networks {ext.n} points: shard_map over a "
-        f"{mesh.devices.size}-device mesh, both calls "
+    log(f"[4] evaluate_networks {ext.n} points: one round over the "
+        f"{mesh.devices.size} chips, both calls "
         f"{time.perf_counter() - t0:.2f}s incl. compile (smoke time)")
     check(mesh.devices.size == len(devs),
           f"mesh holds {mesh.devices.size} devices, not {len(devs)}")

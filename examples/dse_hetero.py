@@ -22,8 +22,7 @@ def main():
         {n: topology.get_network(n) for n in topology.NETWORKS})
     # self-describing output: what the engine actually executed on
     print(f"engine backend: {energymodel.last_backend()} "
-          f"({energymodel.host_device_count()} host device(s); "
-          f"pallas available: {energymodel.pallas_available()})")
+          f"({energymodel.host_device_count()} host device(s))")
     chip = hetero.design_chip(sweeps, bound=0.05, max_cores=3)
     groups = collections.defaultdict(list)
     for net, i in chip.assignment.items():

@@ -75,8 +75,7 @@ def sweep_networks(networks: Mapping[str, Sequence[Layer]],
     This is the batched entry point: the config cross product is built as
     arrays, all networks' layers share one padded trace, and the jitted
     kernel is cached at module level — repeated sweeps never retrace.
-    ``backend`` picks the heavy-stage kernel (``"pallas"`` routes through
-    the fused count-terms kernel, with auto-fallback to jax/numpy);
+    ``backend`` picks the grid kernel (``"jax"`` or ``"numpy"``);
     ``shard=True`` spreads the config axis over all host devices (see
     :func:`energymodel.request_host_devices`); ``chunk_size`` bounds the
     engine's per-dispatch intermediates on large grids.

@@ -49,16 +49,13 @@ Array-shape conventions of the batched engine (see also
   is the input of the heterogeneous layer→core co-design solver
   (:func:`repro.core.partition.batch_schedule_hetero`).
 
-Three interchangeable backends evaluate the heavy stage (selected by
-``backend=`` on the public entry points, auto-fallback order
-pallas → jax → numpy): the jitted jax kernel, the fused Pallas
-count-terms kernel (:mod:`repro.kernels.count_terms`), and the numpy
-reference.  An unavailable choice degrades silently at the result level
-but emits ONE :class:`RuntimeWarning` per process per degradation edge
-(see :func:`resolve_backend`); :func:`last_backend` always reports what
-actually executed.  On a TPU the device path is ``"jax"``: the Pallas
-kernel does not lower there, and asking for it raises
-:class:`BackendUnavailable` (see :func:`platform`).
+Two backends evaluate the grid kernel (selected by ``backend=`` on the
+public entry points): ``"jax"``, the jitted device path, and ``"numpy"``,
+the reference.  Without jax a ``"jax"`` request degrades to numpy with
+ONE :class:`RuntimeWarning` per process (see :func:`resolve_backend`);
+:func:`last_backend` always reports what actually executed.  Several
+devices share work one way only: whole chunks in rounds, one per device
+(:func:`_jax_device_kernel`).
 """
 
 from __future__ import annotations
@@ -357,10 +354,9 @@ def simulate_network(cfg: AcceleratorConfig, layers: Sequence[Layer],
 
 _LAYER_BUCKET = 256
 
-#: The engine's programs: the grid kernel in its six forms and the three
+#: The engine's programs: the grid kernel in its four forms and the three
 #: streamed folds (every other program counts under its own name too).
 _ENGINE_PROGRAMS = ("grid_kernel", "grid_kernel_layers",
-                    "grid_kernel_sharded", "grid_kernel_sharded_layers",
                     "grid_kernel_devices", "grid_kernel_devices_layers",
                     "stream_fold", "layer_fold", "layer_fold_devices")
 
@@ -623,8 +619,7 @@ def _coef_struct(cfgs: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
 def _term_sums_body(xp, segments, cfg_m, cfg_u, lay, inv_m):
     """Mapping on the mapping-unique rows, counts on the count-unique rows,
     per-network segment sums: tuple of [n_u, n_net] (or [1, n_net] for the
-    two config-independent terms).  This is the heavy stage — and the one
-    the sharded kernel splits along the unique-config axis."""
+    two config-independent terms).  This is the heavy stage."""
     mp_m = _mapping(xp, cfg_m, lay)
     mp = {k: mp_m[k][inv_m] for k in _MAPPING_KEYS}
     terms = _count_terms(xp, cfg_u, lay, mp)
@@ -646,20 +641,11 @@ def _gather_combine_body(xp, S, inv, coefs):
     return _combine_reduced(xp, tuple(gathered), coefs)
 
 
-def _pallas_term_sums(segments, cfg_u, lay):
-    """Fused Pallas twin of :func:`_term_sums_body`: mapping + 14 terms +
-    segment reduction in one pass over the [unique × layers] tiles (see
-    ``repro/kernels/count_terms``).  Runs on the count-unique rows only —
-    the mapping-level dedup is folded into the tile program."""
-    from repro.kernels.count_terms import count_term_sums
-    return count_term_sums(cfg_u, lay, segments)
-
-
 # ---------------------------------------------------------------------------
 # Per-layer path: the SAME heavy stage without the early segment reduction.
-# The 14 terms stay [n_u, L] (the one-hot matmul of the fused kernel is
-# skipped), the coefficient combine broadcasts over the concatenated layer
-# axis, and the result is re-split per network onto a padded n_layer axis.
+# The 14 terms stay [n_u, L], the coefficient combine broadcasts over the
+# concatenated layer axis, and the result is re-split per network onto a
+# padded n_layer axis.
 # ---------------------------------------------------------------------------
 
 
@@ -670,14 +656,6 @@ def _term_layers_body(xp, cfg_m, cfg_u, lay, inv_m):
     mp_m = _mapping(xp, cfg_m, lay)
     mp = {k: mp_m[k][inv_m] for k in _MAPPING_KEYS}
     return _count_terms(xp, cfg_u, lay, mp)
-
-
-def _pallas_term_layers(cfg_u, lay):
-    """Fused Pallas per-layer heavy stage: same tile program with the
-    one-hot segment matmul skipped — emits the [14, n_u, L] per-layer
-    partials directly (see ``repro.kernels.count_terms.count_term_layers``)."""
-    from repro.kernels.count_terms import count_term_layers
-    return count_term_layers(cfg_u, lay)
 
 
 def _layer_axis_len(segments) -> int:
@@ -700,24 +678,16 @@ def _split_layers(xp, arr, segments):
 
 
 def _grid_kernel_body(xp, segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
-                      backend: str = "jax", per_layer: bool = False):
-    """Shared numpy/jax/pallas kernel: mapping on the mapping-unique rows,
-    counts on the count-unique rows, segment-reduce, then coefficient
-    combine.  ``backend="pallas"`` swaps the heavy stage for the fused
-    count-terms kernel (same operands, same [n_u, n_net] partial sums).
+                      per_layer: bool = False):
+    """Shared numpy/jax kernel: mapping on the mapping-unique rows, counts
+    on the count-unique rows, segment-reduce, then coefficient combine.
     ``per_layer=True`` skips the segment reduction: the combine runs on
     the [*, L] terms and the outputs are re-split to [n, n_net, n_layer]."""
     if per_layer:
-        if backend == "pallas":
-            S = _pallas_term_layers(cfg_u, lay)
-        else:
-            S = _term_layers_body(xp, cfg_m, cfg_u, lay, inv_m)
+        S = _term_layers_body(xp, cfg_m, cfg_u, lay, inv_m)
         e, t = _gather_combine_body(xp, S, inv, coefs)
         return _split_layers(xp, e, segments), _split_layers(xp, t, segments)
-    if backend == "pallas":
-        S = _pallas_term_sums(segments, cfg_u, lay)
-    else:
-        S = _term_sums_body(xp, segments, cfg_m, cfg_u, lay, inv_m)
+    S = _term_sums_body(xp, segments, cfg_m, cfg_u, lay, inv_m)
     return _gather_combine_body(xp, S, inv, coefs)
 
 
@@ -727,114 +697,27 @@ def _np_grid_kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
                              coefs, per_layer=per_layer)
 
 
-_jitted_grid_kernels: Dict[Tuple[str, bool], Any] = {}  # per (backend, mode)
+_jitted_grid_kernels: Dict[bool, Any] = {}      # per mode (per_layer)
 
 
-def _jax_grid_kernel(backend: str = "jax", per_layer: bool = False):
-    key = (backend, per_layer)
-    if key not in _jitted_grid_kernels:
+def _jax_grid_kernel(per_layer: bool = False):
+    if per_layer not in _jitted_grid_kernels:
         import jax.numpy as jnp
 
         def kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs):
             return _grid_kernel_body(jnp, segments, cfg_m, cfg_u, lay,
-                                     inv_m, inv, coefs, backend=backend,
-                                     per_layer=per_layer)
+                                     inv_m, inv, coefs, per_layer=per_layer)
 
         name = "grid_kernel_layers" if per_layer else "grid_kernel"
-        _jitted_grid_kernels[key] = obs.Program(name, kernel,
-                                                static_argnums=0)
-    return _jitted_grid_kernels[key]
+        _jitted_grid_kernels[per_layer] = obs.Program(name, kernel,
+                                                      static_argnums=0)
+    return _jitted_grid_kernels[per_layer]
 
 
-#: Indices in the `_count_terms` tuple that do not depend on the config
-#: (shape [1, L]): pure-MAC and pooling op counts.
-_CFG_INDEP_TERMS = (6, 7)
-
-_jitted_sharded_kernels: Dict[Tuple[str, bool], Any] = {}
-_sharded_kernel_ndev = 0
+_jitted_device_kernels: Dict[Tuple[bool, int], Any] = {}
 
 
-def _jax_sharded_kernel(backend: str = "jax", per_layer: bool = False):
-    """Sharded twin of :func:`_jax_grid_kernel`, built on ``shard_map``:
-    the count-unique config rows are split along a 1-D device mesh, each
-    device runs the heavy (rows × layers) stage on its slice, and the tiny
-    [n_u, n_net] partial sums are all-gathered before the replicated
-    gather/combine.  Explicit specs — GSPMD's auto-partitioning of the
-    same program chooses badly on CPU meshes."""
-    global _jitted_sharded_kernels, _sharded_kernel_ndev
-    mesh = _cfg_mesh()
-    if _sharded_kernel_ndev != mesh.devices.size:
-        _jitted_sharded_kernels = {}         # device count changed: rebuild
-        _sharded_kernel_ndev = mesh.devices.size
-    key = (backend, per_layer)
-    if key not in _jitted_sharded_kernels:
-        def kernel(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs):
-            return _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m,
-                                      inv, coefs, backend=backend,
-                                      per_layer=per_layer)
-
-        name = ("grid_kernel_sharded_layers" if per_layer
-                else "grid_kernel_sharded")
-        _jitted_sharded_kernels[key] = obs.Program(name, kernel,
-                                                   static_argnums=0)
-    return _jitted_sharded_kernels[key]
-
-
-def _sharded_grid_body(segments, cfg_m, cfg_u, lay, inv_m, inv, coefs,
-                       backend: str = "jax", per_layer: bool = False):
-    """Traced body of the sharded kernel (shared with the stream step).
-
-    In per-layer mode the all-gathered partials are [n_u, L] instead of
-    [n_u, n_net] — heavier across the mesh, but the split along the
-    unique-config axis (and the replicated combine) is identical."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-
-    mesh = _cfg_mesh()
-    row2, row1, rep = P("cfg", None), P("cfg"), P()
-
-    def local(cfg_m_, cfg_u_, lay_, inv_m_):
-        if per_layer:
-            if backend == "pallas":
-                S = _pallas_term_layers(cfg_u_, lay_)
-                return tuple(lax.all_gather(s, "cfg", axis=0, tiled=True)
-                             for s in S)
-            S = _term_layers_body(jnp, cfg_m_, cfg_u_, lay_, inv_m_)
-            return tuple(
-                s if i in _CFG_INDEP_TERMS
-                else lax.all_gather(s, "cfg", axis=0, tiled=True)
-                for i, s in enumerate(S))
-        if backend == "pallas":
-            # the fused kernel emits every term per count-unique row (the
-            # config-independent ones broadcast), so all 14 gather
-            S = _pallas_term_sums(segments, cfg_u_, lay_)
-            return tuple(lax.all_gather(s, "cfg", axis=0, tiled=True)
-                         for s in S)
-        S = _term_sums_body(jnp, segments, cfg_m_, cfg_u_, lay_, inv_m_)
-        return tuple(
-            s if i in _CFG_INDEP_TERMS
-            else lax.all_gather(s, "cfg", axis=0, tiled=True)
-            for i, s in enumerate(S))
-
-    S = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=({k: rep for k in cfg_m}, {k: row2 for k in cfg_u},
-                  {k: rep for k in lay}, row1),
-        out_specs=tuple(rep for _ in range(14)),
-        check_vma=False)(cfg_m, cfg_u, lay, inv_m)
-    if per_layer:
-        e, t = _gather_combine_body(jnp, S, inv, coefs)
-        return (_split_layers(jnp, e, segments),
-                _split_layers(jnp, t, segments))
-    return _gather_combine_body(jnp, S, inv, coefs)
-
-
-_jitted_device_kernels: Dict[Tuple[str, bool, int], Any] = {}
-
-
-def _jax_device_kernel(backend: str = "jax", per_layer: bool = False):
+def _jax_device_kernel(per_layer: bool = False):
     """The grid kernel on every device of the mesh at once, each on its
     own chunk and with no collective: one round of a chunked sweep.  The
     devices' chunk inputs are stacked along the row axis
@@ -848,7 +731,7 @@ def _jax_device_kernel(backend: str = "jax", per_layer: bool = False):
     from jax.sharding import PartitionSpec as P
 
     mesh = _cfg_mesh()
-    key = (backend, per_layer, mesh.devices.size)
+    key = (per_layer, mesh.devices.size)
     if key not in _jitted_device_kernels:
         rows, rep = P("cfg"), P()
 
@@ -857,7 +740,7 @@ def _jax_device_kernel(backend: str = "jax", per_layer: bool = False):
                 def run():
                     return _grid_kernel_body(
                         jnp, segments, cfg_m_, cfg_u_, lay_, inv_m_, inv_,
-                        coefs_, backend=backend, per_layer=per_layer)
+                        coefs_, per_layer=per_layer)
 
                 out = jax.eval_shape(run)
                 return jax.lax.cond(
@@ -891,42 +774,17 @@ def jax_available() -> bool:
 def platform() -> str:
     """The platform the device path runs on (``jax.default_backend()``:
     ``"cpu"``, ``"tpu"``, ...); ``"cpu"`` without jax.  Every platform
-    decision of the engine — which backend is the device path, whether
-    Pallas kernels run interpreted — reads this one function."""
+    decision — whether the service's retries may end on the numpy
+    reference, whether kernels run interpreted
+    (``repro.kernels.default_interpret``) — reads this one function."""
     if not jax_available():
         return "cpu"                                   # pragma: no cover
     import jax
     return jax.default_backend()
 
 
-#: Why the fused count-terms Pallas kernel cannot run on a TPU.
-_PALLAS_TPU_REFUSAL = (
-    "Mosaic refuses the count-terms tile program: it is float64 ('64-bit "
-    "types are not supported'), and in float32 it fails to legalize; it "
-    "needs an exact non-f64 count format first (ROADMAP Queue 1 item 2)")
-
-
-class BackendUnavailable(RuntimeError):
-    """An explicitly requested backend cannot run on this platform, and
-    degrading to another would hide the device (see
-    :func:`resolve_backend`)."""
-
-
-def pallas_available() -> bool:
-    """Whether the fused count-terms Pallas kernel can run here: on the
-    CPU it runs interpreted; on a TPU the tile program does not lower
-    (``_PALLAS_TPU_REFUSAL``), so the device path there is ``"jax"``."""
-    if not jax_available():
-        return False                                   # pragma: no cover
-    try:
-        from jax.experimental import pallas            # noqa: F401
-    except Exception:                                  # pragma: no cover
-        return False
-    return platform() != "tpu"
-
-
-#: Selectable heavy-stage backends, in auto-fallback order.
-BACKENDS = ("pallas", "jax", "numpy")
+#: Selectable grid-kernel backends, in auto-fallback order.
+BACKENDS = ("jax", "numpy")
 
 _LAST_BACKEND: str | None = None
 
@@ -938,7 +796,7 @@ _FALLBACK_WARNED: set = set()
 
 def last_backend() -> str | None:
     """Backend the most recent engine dispatch actually ran on
-    (``"pallas" | "jax" | "numpy"``), after auto-fallback — ``None``
+    (``"jax" | "numpy"``), after auto-fallback — ``None``
     before the first call.  Lets callers report truthfully what executed
     (see ``examples/dse_hetero.py``)."""
     return _LAST_BACKEND
@@ -960,14 +818,11 @@ def resolve_backend(backend: str | None = None,
                     use_jax: bool | None = None) -> str:
     """Resolve the requested backend with auto-fallback.
 
-    Explicit ``backend`` wins over the legacy ``use_jax`` tri-state; an
-    unavailable choice degrades (pallas → jax → numpy) instead of
-    raising, so ``backend="pallas"`` is safe on hosts without Pallas.
-    Each degradation edge emits one ``RuntimeWarning`` per process (not
-    per call); the silent paths are only the auto-selections where
-    nothing was requested.  On a platform where the Pallas kernel cannot
-    lower (a TPU) an explicit ``"pallas"`` raises
-    :class:`BackendUnavailable` instead of degrading."""
+    Explicit ``backend`` wins over the legacy ``use_jax`` tri-state; a
+    name outside :data:`BACKENDS` raises ``ValueError``.  ``"jax"``
+    degrades to ``"numpy"`` when jax cannot be imported, with one
+    ``RuntimeWarning`` per process (not per call); the silent path is
+    only the auto-selection where nothing was requested."""
     if backend is None:
         if use_jax is None:
             backend = "jax" if jax_available() else "numpy"
@@ -979,12 +834,6 @@ def resolve_backend(backend: str | None = None,
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, "
                          f"got {backend!r}")
-    if backend == "pallas" and platform() == "tpu":
-        raise BackendUnavailable(
-            f"backend 'pallas' cannot run on a TPU: {_PALLAS_TPU_REFUSAL}; "
-            "use backend='jax'")
-    if backend == "pallas" and not pallas_available():
-        backend = "jax"
     if backend == "jax" and not jax_available():
         backend = "numpy"
     if requested is not None and backend != requested:
@@ -993,11 +842,10 @@ def resolve_backend(backend: str | None = None,
 
 
 # ---------------------------------------------------------------------------
-# Device sharding: the deduped config axis is the embarrassingly-parallel
-# axis of the engine — the heavy (unique-rows × layers) math partitions
-# cleanly across host devices, and only the tiny [unique, networks] reduced
-# sums cross device boundaries (one all-gather before the coefficient
-# combine).  Multiple host devices come from XLA's
+# Several devices: the config axis is the embarrassingly-parallel axis of
+# the engine, so whole chunks of it go out in rounds, one per device, each
+# device running the complete kernel on its chunk with no collective
+# (:func:`_jax_device_kernel`).  Multiple host devices come from XLA's
 # ``--xla_force_host_platform_device_count`` flag, which MUST be set in
 # XLA_FLAGS before jax first initialises its backend (see launch/dryrun.py
 # and benchmarks/run.py for the pattern).
@@ -1048,22 +896,6 @@ def _cfg_mesh():
     return _MESH
 
 
-def _device_put_sharded(cfg_m, cfg_u, lay, inv_m, inv, coefs):
-    """Place kernel inputs: unique-config rows split along the mesh, the
-    small mapping rows / layer axis / coefficients replicated."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec
-    mesh = _cfg_mesh()
-    row = NamedSharding(mesh, PartitionSpec("cfg"))
-    rep = NamedSharding(mesh, PartitionSpec())
-    put = jax.device_put
-    return ({k: put(v, rep) for k, v in cfg_m.items()},
-            {k: put(v, row) for k, v in cfg_u.items()},
-            {k: put(v, rep) for k, v in lay.items()},
-            put(inv_m, row), put(inv, rep),
-            {k: put(v, rep) for k, v in coefs.items()})
-
-
 def _pad_rows(arr: np.ndarray, n_to: int) -> np.ndarray:
     """Pad axis 0 to ``n_to`` by repeating row 0 (benign duplicate)."""
     if arr.shape[0] >= n_to:
@@ -1074,27 +906,16 @@ def _pad_rows(arr: np.ndarray, n_to: int) -> np.ndarray:
 
 def _prepare_fields(fields: Dict[str, np.ndarray],
                     u_bucket: int | None = None,
-                    m_bucket: int | None = None,
-                    n_dev: int = 1,
-                    backend: str = "jax"):
+                    m_bucket: int | None = None):
     """Grid columns → two-level-deduped kernel inputs, with the unique
-    axes optionally padded to bucket multiples (and to a device-count
-    multiple so the shard along the mesh is even).  The fused Pallas
-    backend recomputes the mapping per count-unique row inside the tile
-    program, so its mapping-level operands are never read — feed
-    stable-shape placeholders instead of running the dedup."""
+    axes optionally padded to bucket multiples."""
     cfgs = _cfg_struct_from_grid(np, fields)
     coefs = _coef_struct(cfgs)
     cfg_u, inv = _dedup_count_rows(cfgs)            # counts level
-    if backend == "pallas":
-        cfg_m = {k: cfg_u[k][:1].copy() for k in _MAPPING_COLUMNS}
-        inv_m = np.zeros(next(iter(cfg_u.values())).shape[0], np.int32)
-    else:
-        cfg_m, inv_m = _dedup_rows(cfg_u, _MAPPING_COLUMNS)  # mapping lvl
+    cfg_m, inv_m = _dedup_rows(cfg_u, _MAPPING_COLUMNS)  # mapping level
     n_u = inv_m.shape[0]
-    if u_bucket is not None or n_dev > 1:
-        tgt = _bucketed(n_u, u_bucket) if u_bucket else n_u
-        tgt = -(-tgt // n_dev) * n_dev
+    if u_bucket is not None:
+        tgt = _bucketed(n_u, u_bucket)
         if tgt > n_u:
             cfg_u = {k: _pad_rows(v, tgt) for k, v in cfg_u.items()}
             inv_m = np.concatenate(
@@ -1108,54 +929,44 @@ def _prepare_fields(fields: Dict[str, np.ndarray],
     return cfg_m, cfg_u, inv_m, inv, coefs
 
 
-def _eval_fields(fields, lay, segments, backend: str, shard: bool,
+def _eval_fields(fields, lay, segments, backend: str,
                  u_bucket: int | None = None,
                  m_bucket: int | None = None,
                  per_layer: bool = False):
-    """Evaluate one batch of grid columns → ([n, n_net], [n, n_net])
-    (or [n, n_net, n_layer] pairs in per-layer mode)."""
-    use_jax = backend != "numpy"
-    n_dev = host_device_count() if (shard and use_jax) else 1
+    """Evaluate one batch of grid columns on one device → ([n, n_net],
+    [n, n_net]) (or [n, n_net, n_layer] pairs in per-layer mode)."""
     cfg_m, cfg_u, inv_m, inv, coefs = _prepare_fields(
-        fields, u_bucket, m_bucket, n_dev, backend)
-    if not use_jax:
+        fields, u_bucket, m_bucket)
+    if backend == "numpy":
         e, t = _np_grid_kernel(segments, cfg_m, cfg_u, lay, inv_m, inv,
                                coefs, per_layer=per_layer)
         return np.asarray(e), np.asarray(t)
     with x64():
-        args = (cfg_m, cfg_u, lay, inv_m, inv, coefs)
-        if n_dev > 1:
-            args = _device_put_sharded(*args)
-            kern = _jax_sharded_kernel(backend, per_layer)
-        else:
-            kern = _jax_grid_kernel(backend, per_layer)
-        return obs.fetch("dse.eval.wait", *kern(segments, *args))
+        return obs.fetch("dse.eval.wait", *_jax_grid_kernel(per_layer)(
+            segments, cfg_m, cfg_u, lay, inv_m, inv, coefs))
 
 
-def _prepare_chunk(fc, backend: str = "jax"):
+def _prepare_chunk(fc):
     """One padded chunk's kernel inputs at the stream's buckets, counted
     in ``dse.stream.rows_unique`` / ``dse.stream.rows_padded``."""
     with obs.span("dse.stream.prep"):
-        prep = _prepare_fields(fc, _UNIQUE_BUCKET, _MAPPING_BUCKET,
-                               backend=backend)
+        prep = _prepare_fields(fc, _UNIQUE_BUCKET, _MAPPING_BUCKET)
     obs.count("dse.stream.rows_unique", int(prep[3].max()) + 1)
     obs.count("dse.stream.rows_padded", prep[2].shape[0])
     return prep
 
 
-def _dispatch_chunk(fc, lay, segments, backend: str = "jax",
-                    per_layer: bool = False):
+def _dispatch_chunk(fc, lay, segments, per_layer: bool = False):
     """Async-dispatch one padded chunk (jax path): returns uncollected
     device arrays so the host can prepare the next chunk while this one
     runs."""
-    cfg_m, cfg_u, inv_m, inv, coefs = _prepare_chunk(fc, backend)
+    cfg_m, cfg_u, inv_m, inv, coefs = _prepare_chunk(fc)
     with obs.span("dse.stream.dispatch"):
-        return _jax_grid_kernel(backend, per_layer)(
+        return _jax_grid_kernel(per_layer)(
             segments, cfg_m, cfg_u, lay, inv_m, inv, coefs)
 
 
-def _dispatch_chunks(chunks, lay, segments, backend: str, per_layer: bool,
-                     shard: bool):
+def _dispatch_chunks(chunks, lay, segments, per_layer: bool, shard: bool):
     """Async-dispatch every chunk of ``chunks`` (``(ci, start, stop, fc)``)
     and yield ``(ci, start, stop, fc, e, t)`` with ``e``/``t`` on the
     device that computes them.  With ``shard`` and several devices the
@@ -1164,16 +975,15 @@ def _dispatch_chunks(chunks, lay, segments, backend: str, per_layer: bool,
     if not (shard and host_device_count() > 1):
         for ci, start, stop, fc in chunks:
             yield (ci, start, stop, fc,
-                   *_dispatch_chunk(fc, lay, segments, backend, per_layer))
+                   *_dispatch_chunk(fc, lay, segments, per_layer))
         return
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = _cfg_mesh()
     lay_d = jax.device_put(lay, NamedSharding(mesh, P()))
-    kern = _jax_device_kernel(backend, per_layer)
+    kern = _jax_device_kernel(per_layer)
     for rnd in _rounds(chunks, mesh.devices.size):
-        args = _round_inputs([_prepare_chunk(fc, backend) for *_, fc in rnd],
-                             mesh)
+        args = _round_inputs([_prepare_chunk(fc) for *_, fc in rnd], mesh)
         with obs.span("dse.stream.dispatch"):
             e_g, t_g = kern(segments, args[0], args[1], lay_d, *args[2:])
         e_on = {s.device: s.data for s in e_g.addressable_shards}
@@ -1250,7 +1060,7 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
 
     if backend == "numpy":
         for _, start, stop, fc in chunks():
-            ec, tc = _eval_fields(fc, lay, segments, "numpy", False,
+            ec, tc = _eval_fields(fc, lay, segments, "numpy",
                                   _UNIQUE_BUCKET, _MAPPING_BUCKET,
                                   per_layer=per_layer)
             e[start:stop] = ec[:stop - start]
@@ -1267,7 +1077,7 @@ def _eval_chunked(fields, lay, segments, backend: str, shard: bool,
 
     with x64():
         for _, start, stop, _, ec, tc in _dispatch_chunks(
-                chunks(), lay, segments, backend, per_layer, shard):
+                chunks(), lay, segments, per_layer, shard):
             pending.append((start, stop, ec, tc))
             if len(pending) > 2 * n_dev:
                 drain(pending.pop(0))
@@ -1289,15 +1099,16 @@ def evaluate_networks(grid: ConfigGrid,
 
     Returns ``(energy, latency)`` float64 arrays of shape
     ``[grid.n, len(networks)]``, columns ordered like ``networks``.
-    ``backend`` selects the heavy-stage kernel — ``"pallas"`` (fused
-    count-terms kernel), ``"jax"`` (jitted term chains), ``"numpy"``
-    (reference) — with auto-fallback when the choice is unavailable; the
-    legacy ``use_jax`` tri-state maps onto it (None auto-selects).
-    ``shard=True`` splits the deduped config axis across all host devices
-    (see :func:`request_host_devices`); ``chunk_size`` evaluates the grid
-    in fixed-shape chunks so the heavy (unique-rows × layers)
-    intermediates stay bounded — mega-scale spaces would otherwise
-    materialise multi-GB tiles.
+    ``backend`` selects the grid kernel — ``"jax"`` (the jitted device
+    path) or ``"numpy"`` (reference) — with auto-fallback when jax is
+    unavailable; the legacy ``use_jax`` tri-state maps onto it (None
+    auto-selects).  ``chunk_size`` evaluates the grid in fixed-shape
+    chunks so the heavy (unique-rows × layers) intermediates stay
+    bounded — mega-scale spaces would otherwise materialise multi-GB
+    tiles.  ``shard=True`` sends the chunks out in rounds over all host
+    devices (see :func:`request_host_devices`); with no ``chunk_size``
+    that is one round, a chunk of ``ceil(n / n_devices)`` rows on each
+    device.
 
     ``per_layer=True`` keeps the layer axis: the outputs become
     ``[grid.n, len(networks), n_layer]`` where ``n_layer`` is the longest
@@ -1316,13 +1127,15 @@ def evaluate_networks(grid: ConfigGrid,
     fields = grid.fields if isinstance(grid, ConfigGrid) else dict(grid)
     n = int(next(iter(fields.values())).shape[0])
 
+    n_dev = host_device_count() if backend != "numpy" and shard else 1
+    if chunk_size is None and n_dev > 1:
+        chunk_size = -(-n // n_dev)                  # one round
     if chunk_size is not None and n > chunk_size:
         return _eval_chunked(fields, lay, segments, backend, shard,
                              chunk_size, n, len(networks),
                              per_layer=per_layer)
 
-    return _eval_fields(fields, lay, segments, backend, shard,
-                        per_layer=per_layer)
+    return _eval_fields(fields, lay, segments, backend, per_layer=per_layer)
 
 
 # ---------------------------------------------------------------------------
@@ -1664,11 +1477,11 @@ def stream_networks(grid: ConfigGrid,
     """Chunked streaming sweep with on-device running reductions.
 
     Never materialises the full ``[n_cfg, n_net]`` matrices: each chunk is
-    evaluated (optionally sharded across host devices) and folded into
+    evaluated (optionally in rounds over the host devices) and folded into
     per-network running minima, top-k cells, and ≤``bound`` boundary
     candidate sets.  Equivalent to reducing :func:`evaluate_networks`'s
     output, at bounded memory.  ``backend`` routes the per-chunk kernel
-    like :func:`evaluate_networks` (pallas / jax / numpy, auto-fallback).
+    like :func:`evaluate_networks` (jax / numpy, auto-fallback).
 
     Crash-safety: ``on_chunk`` receives a :class:`StreamFoldState` after
     every folded chunk; pass one back as ``resume_from=`` to restart from
@@ -1713,7 +1526,7 @@ def stream_networks(grid: ConfigGrid,
         verify.bind(kind="networks", names=names, metric=metric,
                     topk=topk, bound=bound, backend=backend,
                     ref_eval=lambda fc: _eval_fields(
-                        fc, lay, segments, "numpy", False,
+                        fc, lay, segments, "numpy",
                         _UNIQUE_BUCKET, _MAPPING_BUCKET))
         if resume_from is not None:
             verify.check_resume(state, cand)
@@ -1808,7 +1621,7 @@ def stream_networks(grid: ConfigGrid,
                 emit(ci)
 
             for ci, start, stop, fc, e_d, t_d in _dispatch_chunks(
-                    chunks(), lay, segments, backend, False, n_dev > 1):
+                    chunks(), lay, segments, False, n_dev > 1):
                 pending.append((ci, start, stop, e_d, t_d,
                                 fc if verify is not None else None))
                 if len(pending) > 2 * n_dev:
@@ -2071,9 +1884,9 @@ def _merge_layer_states(states: Sequence[tuple]) -> tuple:
             lmin, larg)
 
 
-def _layer_topk_rounds(chunks, state, lay, segments, backend, metric, k,
-                       bound, lay_valid, chunk, names, nan_guard, verify,
-                       collect, emit):
+def _layer_topk_rounds(chunks, state, lay, segments, metric, k, bound,
+                       lay_valid, chunk, names, nan_guard, verify, collect,
+                       emit):
     """The jax path of :func:`stream_layer_topk` over every device of the
     mesh.  Chunks go out in rounds, one chunk per device, and each round
     is one launch of the grid kernel and one of the fold for all devices
@@ -2092,7 +1905,7 @@ def _layer_topk_rounds(chunks, state, lay, segments, backend, metric, k,
     mesh = _cfg_mesh()
     n_dev = mesh.devices.size
     rows = NamedSharding(mesh, P("cfg"))
-    kern = _jax_device_kernel(backend, per_layer=True)
+    kern = _jax_device_kernel(per_layer=True)
     fold = _jax_layer_fold_devices()
     b = 0.0 if bound is None else float(bound)
     init = _layer_fold_init(k, *lay_valid.shape)
@@ -2139,8 +1952,8 @@ def _layer_topk_rounds(chunks, state, lay, segments, backend, metric, k,
             tuple(np.stack([s] + [i] * (n_dev - 1))
                   for s, i in zip(state, init)), rows)
         for rnd in _rounds(chunks, n_dev):
-            args = _round_inputs(
-                [_prepare_chunk(fc, backend) for *_, fc in rnd], mesh)
+            args = _round_inputs([_prepare_chunk(fc) for *_, fc in rnd],
+                                 mesh)
             pad = [0] * (n_dev - len(rnd))
             base = np.array([s for _, s, _, _ in rnd] + pad, np.int64)
             valid = np.array([p - s for _, s, p, _ in rnd] + pad, np.int64)
@@ -2255,7 +2068,7 @@ def stream_layer_topk(grid: ConfigGrid,
         verify.bind(kind="layer_topk", names=names, metric=metric,
                     topk=k, bound=bound, backend=backend,
                     ref_eval=lambda fc: _eval_fields(
-                        fc, lay, segments, "numpy", False,
+                        fc, lay, segments, "numpy",
                         _UNIQUE_BUCKET, _MAPPING_BUCKET, per_layer=True))
         if resume_from is not None:
             verify.check_resume(state, cand)
@@ -2294,7 +2107,7 @@ def stream_layer_topk(grid: ConfigGrid,
 
     if backend == "numpy":
         for ci, start, stop, fc in chunks():
-            ec, tc = _eval_fields(fc, lay, segments, "numpy", False,
+            ec, tc = _eval_fields(fc, lay, segments, "numpy",
                                   _UNIQUE_BUCKET, _MAPPING_BUCKET,
                                   per_layer=True)
             ec, tc = _apply_chunk_hook(ci, ec, tc)
@@ -2314,8 +2127,8 @@ def stream_layer_topk(grid: ConfigGrid,
             emit(ci, state)
     elif shard and host_device_count() > 1:
         state = _layer_topk_rounds(
-            chunks(), state, lay, segments, backend, metric, k, bound,
-            lay_valid, chunk, names, nan_guard, verify, collect,
+            chunks(), state, lay, segments, metric, k, bound, lay_valid,
+            chunk, names, nan_guard, verify, collect,
             emit if on_chunk is not None else None)
     else:
         # Each chunk's kernel and fold are enqueued together, and chunk i
@@ -2352,8 +2165,7 @@ def stream_layer_topk(grid: ConfigGrid,
 
             dstate = state
             for ci, start, stop, fc in chunks():
-                e_d, t_d = _dispatch_chunk(fc, lay, segments, backend,
-                                           per_layer=True)
+                e_d, t_d = _dispatch_chunk(fc, lay, segments, per_layer=True)
                 if _CHUNK_HOOK is not None:     # faults fire with every
                     for item in pending:        # earlier chunk committed
                         finish(item, False)
@@ -2495,7 +2307,7 @@ def simulate_grid(configs: Sequence[AcceleratorConfig] | ConfigGrid,
     ``use_jax=True`` evaluates the whole design space inside the batched,
     module-level jit-cached engine under 64-bit mode (counts exceed
     float32's integer range); repeated same-shape sweeps reuse the compile.
-    ``backend`` overrides the kernel choice (pallas / jax / numpy).
+    ``backend`` overrides the kernel choice (jax / numpy).
     """
     grid = (configs if isinstance(configs, ConfigGrid)
             else ConfigGrid.from_configs(configs))

@@ -28,7 +28,7 @@ defense-in-depth ladder against exactly that:
      fast-path result: bit-exactly when the fast path IS numpy, within
      ``SHADOW_RTOL`` (1e-12, ~2 decades above the measured cross-backend
      rounding noise and ~9 decades below the injected 1e-3
-     perturbation) for jax/pallas.  A mismatch raises
+     perturbation) for jax.  A mismatch raises
      :class:`ShadowMismatchError` with provenance down to (grid row,
      network, term).  This is the layer that catches finite wrong chunk
      evaluations.

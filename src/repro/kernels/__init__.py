@@ -6,6 +6,6 @@
 def default_interpret() -> bool:
     """Whether Pallas kernels run in interpret mode: on the CPU, which
     has no native lowering, and nowhere else — an accelerator runs the
-    compiled kernel or none (see ``energymodel.pallas_available``)."""
+    compiled kernel or none (the platform is ``energymodel.platform``)."""
     from repro.core.energymodel import platform
     return platform() == "cpu"

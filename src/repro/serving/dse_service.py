@@ -42,9 +42,9 @@ Scenarios that kill every core come back ``feasible=False`` per network
    budget mid-stream — is answered from the subsampled grid and flagged
    ``degraded=True``.
 3. *Retry with exponential backoff*: transient backend failures re-run the
-   computation after ``backoff_s · 2^attempt``, walking down the engine's
-   pallas → jax → numpy fallback chain after repeated failures.  Off the
-   CPU the chain ends at the device path: a fault that outlasts the
+   computation after ``backoff_s · 2^attempt``, stepping from the engine's
+   jax device path to the numpy reference after repeated failures.  Off
+   the CPU the ladder is the device path alone: a fault that outlasts the
    retries raises :class:`ServiceFault` rather than answer from numpy.
 4. *Checkpoint/resume*: every streamed sweep exports its
    :class:`repro.core.energymodel.StreamFoldState` after each chunk; a
@@ -191,6 +191,7 @@ class DSEService:
         self.topk = max(int(topk), int(pool_size))
         self.chunk_size = int(chunk_size)
         self.max_queue = int(max_queue)
+        energymodel.resolve_backend(backend)    # an unknown name: ValueError
         self.backend = backend
         self.max_retries = int(max_retries)
         self.backoff_s = float(backoff_s)

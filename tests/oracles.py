@@ -157,3 +157,41 @@ def loop_pareto_scores(value, latency, deadlines):
                 if value[c, j] < net_s[j]:
                     net_s[j], best_net[j, d] = value[c, j], c
     return best, best_net
+
+
+# ---------------------------------------------------------------------------
+# The engine's heavy stage without the two-level dedup
+# ---------------------------------------------------------------------------
+
+
+def count_term_sums_ref(cfg_u, lay, segments):
+    """Dedup-free oracle of ``energymodel._term_sums_body``: the RS mapping
+    runs directly on the count-unique rows (no mapping-level dedup and
+    gather) and the layer axis is collapsed with static per-network
+    segment slices.  cfg_u: dict of [n_u, 1] count columns; lay: dict of
+    [1, L] layer columns; segments: ((start, stop), ...) per network.
+    Returns the stacked [14, n_u, n_net] partial sums (config-independent
+    terms broadcast along the unique axis)."""
+    import jax.numpy as jnp
+
+    from repro.core import energymodel
+    terms = energymodel._count_terms(jnp, cfg_u, lay)
+    n_u = cfg_u[next(iter(cfg_u))].shape[0]
+    return jnp.stack([
+        jnp.broadcast_to(
+            jnp.stack([t[..., a:b].sum(-1) for a, b in segments], axis=-1),
+            (n_u, len(segments)))
+        for t in terms])
+
+
+def count_term_layers_ref(cfg_u, lay):
+    """Per-layer twin of :func:`count_term_sums_ref` (of
+    ``energymodel._term_layers_body``): the raw [14, n_u, L] term stack,
+    config-independent terms broadcast along the unique axis."""
+    import jax.numpy as jnp
+
+    from repro.core import energymodel
+    terms = energymodel._count_terms(jnp, cfg_u, lay)
+    n_u = cfg_u[next(iter(cfg_u))].shape[0]
+    l_tot = lay[next(iter(lay))].shape[-1]
+    return jnp.stack([jnp.broadcast_to(t, (n_u, l_tot)) for t in terms])

@@ -76,7 +76,7 @@ def _compile(fn, *args, static=()):
 def test_grid_kernel_compiles_for_v5e(one_chip, networks, per_layer):
     segments, args = _kernel_args(networks, per_layer)
     assert args[2]["macs"].shape == (1, 2048)
-    compiled = _compile(energymodel._jax_grid_kernel("jax", per_layer),
+    compiled = _compile(energymodel._jax_grid_kernel(per_layer),
                         *_shapes(args, one_chip), static=(segments,))
     # energy + latency in float64; the chip's tiled layout may pad them
     n_layer = energymodel._layer_axis_len(segments) if per_layer else 1
@@ -100,43 +100,71 @@ def test_layer_reduce_step_compiles_for_v5e(one_chip, networks):
              static=("edp", k))
 
 
-def test_device_round_programs_compile_for_v5e(one_chip, topo, networks,
-                                               monkeypatch):
-    """The sharded stream's two programs, the per-layer grid kernel and
-    the fold on all four chips of the v5e 2x2 at once, one chunk of
-    ``N_ROWS`` points each: they compile, and with no collective."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+@pytest.fixture
+def mesh(one_chip, topo, monkeypatch):
+    """All four chips of the v5e 2x2 as the engine's device mesh."""
+    from jax.sharding import Mesh
     mesh = Mesh(np.array(topo.devices), ("cfg",))
     monkeypatch.setattr(energymodel, "_cfg_mesh", lambda: mesh)
     monkeypatch.setattr(energymodel, "_jitted_device_kernels", {})
     monkeypatch.setattr(energymodel, "_jitted_layer_fold_devices", {})
-    rows, rep = NamedSharding(mesh, P("cfg")), NamedSharding(mesh, P())
-    n_dev = mesh.devices.size
+    return mesh
 
-    def stacked(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            (n_dev * np.shape(x)[0],) + np.shape(x)[1:], np.asarray(x).dtype,
-            sharding=rows), tree)
 
+def _stacked(tree, mesh):
+    """One chunk's shapes per device, stacked along the row axis."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rows, n_dev = NamedSharding(mesh, P("cfg")), mesh.devices.size
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (n_dev * np.shape(x)[0],) + np.shape(x)[1:], np.asarray(x).dtype,
+        sharding=rows), tree)
+
+
+def _round_kernel(mesh, networks, per_layer):
+    """The grid kernel of one round, one chunk of ``N_ROWS`` points on
+    each chip, compiled; returns (segments, compiled)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
     segments, (cfg_m, cfg_u, lay, inv_m, inv, coefs) = _kernel_args(
-        networks, True)
-    kernel = _compile(energymodel._jax_device_kernel("jax", True),
-                      stacked(cfg_m), stacked(cfg_u), _shapes(lay, rep),
-                      stacked(inv_m), stacked(inv), stacked(coefs),
-                      stacked(np.ones(1, bool)), static=(segments,))
+        networks, per_layer)
+    return segments, _compile(
+        energymodel._jax_device_kernel(per_layer), _stacked(cfg_m, mesh),
+        _stacked(cfg_u, mesh), _shapes(lay, NamedSharding(mesh, P())),
+        *(_stacked(a, mesh) for a in (inv_m, inv, coefs, np.ones(1, bool))),
+        static=(segments,))
+
+
+def _no_collective(compiled) -> bool:
+    hlo = compiled.as_text()
+    return not any(op in hlo for op in ("all-gather", "all-reduce",
+                                        "collective-permute"))
+
+
+def test_device_round_programs_compile_for_v5e(mesh, networks):
+    """The sharded stream's two programs, the per-layer grid kernel and
+    the fold on all four chips of the v5e 2x2 at once, one chunk of
+    ``N_ROWS`` points each: they compile, and with no collective."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    segments, kernel = _round_kernel(mesh, networks, True)
     n_net, n_layer, k = len(networks), energymodel._layer_axis_len(segments), 8
     e = np.zeros((N_ROWS, n_net, n_layer))
     fold = _compile(
-        energymodel._jax_layer_fold_devices(), stacked(e), stacked(e),
-        stacked(tuple(s[None] for s in energymodel._layer_fold_init(
-            k, n_net, n_layer))),
-        stacked(np.zeros(1, np.int64)), stacked(np.zeros(1, np.int64)),
-        *_shapes((np.zeros(()), np.zeros((n_net, n_layer), bool)), rep),
+        energymodel._jax_layer_fold_devices(), _stacked(e, mesh),
+        _stacked(e, mesh),
+        _stacked(tuple(s[None] for s in energymodel._layer_fold_init(
+            k, n_net, n_layer)), mesh),
+        _stacked(np.zeros(1, np.int64), mesh),
+        _stacked(np.zeros(1, np.int64), mesh),
+        *_shapes((np.zeros(()), np.zeros((n_net, n_layer), bool)),
+                 NamedSharding(mesh, P())),
         static=("edp", k))
-    for compiled in (kernel, fold):
-        hlo = compiled.as_text()
-        assert not any(op in hlo for op in ("all-gather", "all-reduce",
-                                            "collective-permute"))
+    assert _no_collective(kernel) and _no_collective(fold)
+
+
+def test_one_round_aggregate_kernel_compiles_for_v5e(mesh, networks):
+    """The aggregate grid kernel on all four chips at once, which an
+    unchunked ``evaluate_networks(shard=True)`` runs as one round: it
+    compiles, with no collective."""
+    assert _no_collective(_round_kernel(mesh, networks, False)[1])
 
 
 def test_batch_schedule_hetero_solve_compiles_for_v5e(one_chip):

@@ -236,3 +236,11 @@ def test_backend_ladder_ends_at_the_device_path(grid, networks,
     assert stats["backend_fallbacks"] == 0
     assert DSEService(grid, networks,
                       backend="numpy")._backend_ladder() == ["numpy"]
+
+
+def test_unknown_backend_is_refused(grid, networks):
+    """A backend the engine does not know ("pallas" included) is refused
+    when the service is built, with the engine's ValueError, rather than
+    becoming a rung of its retry ladder."""
+    with pytest.raises(ValueError, match="backend must be one of"):
+        DSEService(grid, networks, backend="pallas")

@@ -225,8 +225,7 @@ def _programs(grid, nets):
         cfg_m, cfg_u, inv_m, inv, coefs = energymodel._prepare_fields(
             pts.fields)
         args = (segments, cfg_m, cfg_u, lay, inv_m, inv, coefs)
-        out[energymodel._jax_grid_kernel("jax", per_layer)] = args
-        out[energymodel._jax_sharded_kernel("jax", per_layer)] = args
+        out[energymodel._jax_grid_kernel(per_layer)] = args
     n, n_net, k = 8, len(nets), 4
     lay, segments = energymodel._stack_networks(nets, absorb_pad=False)
     n_layer = energymodel._layer_axis_len(segments)
@@ -261,8 +260,7 @@ def _programs(grid, nets):
     return out
 
 
-PROGRAMS = ("grid_kernel", "grid_kernel_layers", "grid_kernel_sharded",
-            "grid_kernel_sharded_layers", "stream_fold", "layer_fold",
+PROGRAMS = ("grid_kernel", "grid_kernel_layers", "stream_fold", "layer_fold",
             "schedule_stage1", "partition_solve", "pareto_scores",
             "slack_solve")
 

@@ -1,6 +1,6 @@
 """The per-layer metric path (`evaluate_networks(per_layer=True)`): parity
 with the scalar per-layer reports and the aggregate path, across every
-engine variant (numpy/jax/pallas × chunked × sharded), the streaming
+engine variant (numpy/jax × chunked × sharded), the streaming
 per-layer top-k, and the warn-once backend-fallback contract."""
 
 import warnings
@@ -79,19 +79,6 @@ def test_jax_chunked_sharded_parity(networks, grid, per_layer_np):
         np.testing.assert_allclose(t1, tl, rtol=1e-9, err_msg=str(kw))
 
 
-def test_pallas_per_layer_parity(networks, grid, per_layer_np):
-    if not energymodel.pallas_available():              # pragma: no cover
-        pytest.skip("pallas unavailable")
-    el, tl = per_layer_np
-    for kw in (dict(), dict(chunk_size=7), dict(shard=True)):
-        e1, t1 = energymodel.evaluate_networks(grid, networks,
-                                               backend="pallas",
-                                               per_layer=True, **kw)
-        np.testing.assert_allclose(e1, el, rtol=1e-9, err_msg=str(kw))
-        np.testing.assert_allclose(t1, tl, rtol=1e-9, err_msg=str(kw))
-        assert energymodel.last_backend() == "pallas"
-
-
 def test_dse_layer_metrics_wrapper(networks, grid, per_layer_np):
     el, tl = per_layer_np
     e1, t1 = dse.layer_metrics(networks, grid, use_jax=False)
@@ -139,40 +126,24 @@ def test_aggregate_trace_sharing_unaffected(networks):
 
 def test_fallback_warns_exactly_once_per_process(networks, monkeypatch):
     """A degraded explicit backend warns ONCE per process per edge — not
-    per call — and last_backend() reports what actually ran."""
-    monkeypatch.setattr(energymodel, "pallas_available", lambda: False)
+    per call — last_backend() reports what actually ran, and the
+    auto-selection (nothing requested) never warns."""
+    monkeypatch.setattr(energymodel, "jax_available", lambda: False)
     monkeypatch.setattr(energymodel, "_FALLBACK_WARNED", set())
     grid = accelerator.ConfigGrid.product(arrays=((16, 16),),
                                           gb_psum_kb=(54,),
                                           gb_ifmap_kb=(54,))
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
-        energymodel.evaluate_networks(grid, networks, backend="pallas")
-        assert energymodel.last_backend() == "jax"
-        energymodel.evaluate_networks(grid, networks, backend="pallas")
-        energymodel.stream_networks(grid, networks, backend="pallas",
+        energymodel.evaluate_networks(grid, networks, backend="jax")
+        assert energymodel.last_backend() == "numpy"
+        energymodel.evaluate_networks(grid, networks, backend="jax")
+        energymodel.stream_networks(grid, networks, backend="jax",
                                     chunk_size=8)
+        assert energymodel.resolve_backend(None) == "numpy"
+        energymodel.evaluate_networks(grid, networks)
     ours = [w for w in rec if issubclass(w.category, RuntimeWarning)
             and "falling back" in str(w.message)]
     assert len(ours) == 1, [str(w.message) for w in rec]
-    assert "'pallas'" in str(ours[0].message)
-    assert energymodel.last_backend() == "jax"
-
-
-def test_fallback_warning_keyed_per_edge(monkeypatch):
-    monkeypatch.setattr(energymodel, "_FALLBACK_WARNED", set())
-    monkeypatch.setattr(energymodel, "pallas_available", lambda: False)
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        assert energymodel.resolve_backend("pallas") == "jax"
-        assert energymodel.resolve_backend("pallas") == "jax"
-        monkeypatch.setattr(energymodel, "jax_available", lambda: False)
-        assert energymodel.resolve_backend("pallas") == "numpy"
-        assert energymodel.resolve_backend("jax") == "numpy"
-        assert energymodel.resolve_backend("jax") == "numpy"
-        # auto-selection (no explicit request) must never warn
-        assert energymodel.resolve_backend(None) == "numpy"
-    msgs = [str(w.message) for w in rec
-            if issubclass(w.category, RuntimeWarning)]
-    assert len(msgs) == 3                    # pallas→jax, pallas→numpy,
-    assert len(set(msgs)) == 3               # jax→numpy: one each
+    assert "'jax'" in str(ours[0].message)
+    assert energymodel.last_backend() == "numpy"

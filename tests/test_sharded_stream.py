@@ -7,7 +7,8 @@ child process forced to 1, 2 or 4 CPU devices (the count is fixed when
 JAX starts) runs it on a 96-point grid x 3 networks in chunks of 20 (five
 chunks: rounds of 4 + 1 on four devices, 2 + 2 + 1 on two) and compares it
 with the one-device stream bit for bit, with and without boundary sets;
-the chunked dense evaluation likewise, and plants faults in the sharded
+the chunked dense evaluation likewise, and the unchunked one, which runs
+as one round over the devices; and it plants faults in the sharded
 stream.  The same child reports the stream's compiles, spans and
 counters.
 """
@@ -88,16 +89,32 @@ _SCRIPT = textwrap.dedent("""
         out[f"dense_equal_{per_layer}"] = all(
             np.array_equal(x, y) for x, y in zip(a, b))
 
+    # the unchunked dense evaluation with shard=True: one round over the
+    # devices; the engine programs it runs and the compiles it adds
+    for per_layer in (False, True):
+        a = energymodel.evaluate_networks(grid, nets, use_jax=True,
+                                          per_layer=per_layer)
+        calls = {p: obs.total(f"jit.call.{p}")
+                 for p in energymodel._ENGINE_PROGRAMS}
+        n_compiles = len(compiles)
+        b = energymodel.evaluate_networks(grid, nets, use_jax=True,
+                                          per_layer=per_layer, shard=True)
+        out[f"one_round_{per_layer}"] = dict(
+            equal=all(np.array_equal(x, y) for x, y in zip(a, b)),
+            compiles=compiles[n_compiles:],
+            ran=sorted(p for p in calls
+                       if obs.total(f"jit.call.{p}") > calls[p]))
+
     # a round of one chunk: the first device computes it as the
     # one-device kernel does, the others are inactive and return zeros
     with energymodel.x64():
         lay, segments = energymodel._stack_networks(nets, absorb_pad=False)
         lay = {k: v[None, :] for k, v in lay.items()}
         prep = energymodel._prepare_chunk(grid.take(np.arange(20)).fields)
-        one_e, one_t = energymodel._jax_grid_kernel("jax", True)(
+        one_e, one_t = energymodel._jax_grid_kernel(True)(
             segments, prep[0], prep[1], lay, *prep[2:])
         args = energymodel._round_inputs([prep], energymodel._cfg_mesh())
-        e_g, t_g = energymodel._jax_device_kernel("jax", True)(
+        e_g, t_g = energymodel._jax_device_kernel(True)(
             segments, args[0], args[1], lay, *args[2:])
     e_g, t_g = np.asarray(e_g), np.asarray(t_g)
     out["round_first_equal"] = bool(
@@ -149,6 +166,26 @@ def test_sharded_stream_equals_one_device_stream(n_dev):
     assert out["devices"] == n_dev
     assert out["differ"] == {"None": [], "0.05": []}
     assert out["dense_equal_False"] and out["dense_equal_True"]
+
+
+@pytest.mark.parametrize("n_dev", [1, 2, 4])
+def test_unchunked_sharded_dense_is_one_round(n_dev):
+    """``evaluate_networks(shard=True)`` with no ``chunk_size``: on several
+    devices one round of the chunked evaluation, ``ceil(n / n_dev)`` rows
+    on each device, compiled as the device kernel alone; on one device the
+    plain kernel.  Aggregate and per-layer equal ``shard=False`` bit for
+    bit."""
+    out = _child(n_dev)
+    for per_layer in (False, True):
+        r = out[f"one_round_{per_layer}"]
+        assert r["equal"], per_layer
+        suffix = "_layers" if per_layer else ""
+        if n_dev == 1:
+            assert r["ran"] == [f"grid_kernel{suffix}"]
+            assert r["compiles"] == []
+        else:
+            assert r["ran"] == [f"grid_kernel_devices{suffix}"]
+            assert r["compiles"] == [f"jit(grid_kernel_devices{suffix})"]
 
 
 @pytest.mark.parametrize("n_dev", [1, 2, 4])

@@ -140,8 +140,6 @@ def test_codesign_problems_streaming_parity(networks, grid):
                                        pool_size=4)
     combos = [dict(use_jax=False), dict(use_jax=True),
               dict(use_jax=True, shard=True)]
-    if energymodel.pallas_available():
-        combos.append(dict(backend="pallas"))
     for kw in combos:
         for chunk in (7, grid.n):
             sp = hetero.codesign_problems_streaming(
@@ -272,8 +270,6 @@ def test_co_design_streaming_extended_grid_parity(networks):
                           pool_size=6)
     combos = [dict(use_jax=False), dict(use_jax=True),
               dict(use_jax=True, shard=True)]
-    if energymodel.pallas_available():
-        combos.append(dict(backend="pallas"))
     for kw in combos:
         cs = hetero.co_design_streaming(egrid, networks, m_cores=4,
                                         max_types=3, pool_size=6,
